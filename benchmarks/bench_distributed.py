@@ -16,9 +16,9 @@ persisted to BENCH_kernel.json -- modeled HBM bytes/site/step of the
 extended-shard launches, exchange count and ICI bytes per step, the
 overlap round time ``max(t_exchange, t_interior) + t_boundary`` -- plus
 the joint (block_rows, block_words, T, depth, overlap) point the
-autotuner picks.  The sweep runs in a subprocess so the fake-device
-XLA_FLAGS never leak into the parent (benchmarks/run.py may already have
-initialised jax on the real topology).
+autotuner picks.  The sweep, model records included, runs in a child
+process: the fake-device XLA_FLAGS never leak into the parent, and the
+parent never touches JAX, so on a TPU host the child can hold the chips.
 
     PYTHONPATH=src python -m benchmarks.bench_distributed          # full
     PYTHONPATH=src python -m benchmarks.bench_distributed --smoke  # tiny/CI
@@ -45,11 +45,15 @@ SCRIPT = textwrap.dedent("""
     from repro.kernels.fhp_step.ops import pick_block_rows_extended
     from repro.roofline.analysis import sharded_fhp_traffic
 
+    from benchmarks.bench_distributed import _model_records
+
     smoke = sys.argv[1] == "smoke"
+    for rec in _model_records(smoke):
+        print("RECORD " + json.dumps(rec))
     h, w = (32, 512) if smoke else (128, 2048)
     steps = 8 if smoke else 16
     depths = (1, 2, 4) if smoke else (1, 2, 4, 8)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = distributed.make_mesh((2, 2), ("data", "model"))
     hl, wdl = h // 2, w // 32 // 2
     planes = bitplane.pack(jnp.asarray(
         byte_step.make_channel(h, w, density=0.3, seed=0)))
@@ -115,7 +119,7 @@ SCRIPT = textwrap.dedent("""
 
 
 def _model_records(smoke: bool) -> List[Dict]:
-    """Structural records (no subprocess, no timing): the joint autotuner
+    """Structural records (no timing): the joint autotuner
     point and its modeled sharded traffic for representative shard sizes."""
     from repro.kernels.fhp_step.ops import autotune_launch
     from repro.roofline.analysis import sharded_fhp_traffic
@@ -144,19 +148,10 @@ def _model_records(smoke: bool) -> List[Dict]:
     return out
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
-    import jax
-    if smoke is None:
-        smoke = jax.default_backend() != "tpu"
-    records = _model_records(smoke)
-    for r in records:
-        print(f"autotune(shard={r['shard']}),(bh={r['block_rows']} "
-              f"bw={r['block_words']} T={r['T']} d={r['depth']} "
-              f"ov={int(r['overlap'])}),config")
-        print(f"model_hbm_bytes_per_site(shard={r['shard']}),"
-              f"{r['model_hbm_bytes_per_site']:.4f},B")
-        print(f"overlap_speedup_modeled(shard={r['shard']}),"
-              f"{r['overlap_speedup_modeled']:.4f},x")
+def main(smoke: bool = False) -> List[Dict]:
+    """Run the sweep in a child process and collect its records.  This
+    process never touches JAX: on a TPU host the child is the one
+    process that holds the chips."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
@@ -168,10 +163,21 @@ def main(smoke: bool | None = None) -> List[Dict]:
         # CI stays green, breaking the never-empty-trajectory guarantee.
         raise RuntimeError("bench_distributed subprocess failed:\n"
                            f"{r.stdout}\n{r.stderr}")
+    records = []
     for line in r.stdout.splitlines():
-        if line.startswith("RECORD "):
-            rec = json.loads(line[len("RECORD "):])
-            records.append(rec)
+        if not line.startswith("RECORD "):
+            continue
+        rec = json.loads(line[len("RECORD "):])
+        records.append(rec)
+        if rec["structural"]:
+            print(f"autotune(shard={rec['shard']}),(bh={rec['block_rows']} "
+                  f"bw={rec['block_words']} T={rec['T']} d={rec['depth']} "
+                  f"ov={int(rec['overlap'])}),config")
+            print(f"model_hbm_bytes_per_site(shard={rec['shard']}),"
+                  f"{rec['model_hbm_bytes_per_site']:.4f},B")
+            print(f"overlap_speedup_modeled(shard={rec['shard']}),"
+                  f"{rec['overlap_speedup_modeled']:.4f},x")
+        else:
             ov = "_ov" if rec.get("overlap") else ""
             print(f"{rec['impl']}_d{rec['depth']}_T{rec['T']}{ov}_sps,"
                   f"{rec['sites_per_sec']:.3e},sites/s")
@@ -179,4 +185,4 @@ def main(smoke: bool | None = None) -> List[Dict]:
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

@@ -25,10 +25,8 @@ WD = W // 32
 SMOKE_H, SMOKE_W = 64, 1024
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
+def main(smoke: bool = False) -> List[Dict]:
     backend = jax.default_backend()
-    if smoke is None:
-        smoke = False
     h, w = (SMOKE_H, SMOKE_W) if smoke else (H, W)
     wd_full = w // 32
     steps = 2 if smoke else 5
@@ -97,4 +95,4 @@ def main(smoke: bool | None = None) -> List[Dict]:
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

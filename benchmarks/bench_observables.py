@@ -58,10 +58,8 @@ def _time(fn, *args):
     return time.perf_counter() - t0, out
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
+def main(smoke: bool = False) -> List[Dict]:
     backend = jax.default_backend()
-    if smoke is None:
-        smoke = backend != "tpu"
     h, w = (SMOKE_H, SMOKE_W) if smoke else (H, W)
     steps, t_launch = (4, 2) if smoke else (16, 4)
     spec = rulespec.get_rule("fhp2")
@@ -155,4 +153,4 @@ def main(smoke: bool | None = None) -> List[Dict]:
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

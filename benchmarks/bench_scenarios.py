@@ -15,8 +15,9 @@ traffic model (``n_planes``).
 Wall clock is only meaningful on a real multi-chip backend (CPU runs the
 kernel in interpret mode); the durable outputs are the bit-exactness /
 mass assertions (this is the CI scenario smoke sweep) and the model
-columns.  The sweep runs in a subprocess so the fake-device XLA_FLAGS
-never leak into the parent.
+columns.  The sweep, model records included, runs in a child process:
+the fake-device XLA_FLAGS never leak into the parent, and the parent
+never touches JAX, so on a TPU host the child can hold the chips.
 
     PYTHONPATH=src python -m benchmarks.bench_scenarios          # full
     PYTHONPATH=src python -m benchmarks.bench_scenarios --smoke  # tiny/CI
@@ -46,10 +47,14 @@ SCRIPT = textwrap.dedent("""
     from repro.roofline.analysis import sharded_fhp_traffic
     from repro.scenarios import observables
 
+    from benchmarks.bench_scenarios import _model_records
+
     smoke = sys.argv[1] == "smoke"
+    for rec in _model_records(smoke):
+        print("RECORD " + json.dumps(rec))
     h, w = (32, 256) if smoke else (64, 1024)
     steps, depth, T = (8, 4, 2) if smoke else (16, 8, 4)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = distributed.make_mesh((2, 2), ("data", "model"))
     hl, wdl = h // 2, w // 32 // 2
     sh = NamedSharding(mesh, distributed.lattice_spec(("data",), "model"))
     bh = pick_block_rows_extended(wdl + 2, steps=T)
@@ -159,15 +164,10 @@ def _model_records(smoke: bool) -> List[Dict]:
     return out
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
-    import jax
-    if smoke is None:
-        smoke = jax.default_backend() != "tpu"
-    records = _model_records(smoke)
-    for r in records:
-        tag = "static" if r["static_solid"] else "dynamic"
-        print(f"model_ici_bytes_per_site(shard={r['shard']},{tag}),"
-              f"{r['model_ici_bytes_per_site']:.4f},B")
+def main(smoke: bool = False) -> List[Dict]:
+    """Run the sweep in a child process and collect its records.  This
+    process never touches JAX: on a TPU host the child is the one
+    process that holds the chips."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
@@ -178,14 +178,21 @@ def main(smoke: bool | None = None) -> List[Dict]:
         # doubles as the CI scenario smoke gate).
         raise RuntimeError("bench_scenarios subprocess failed:\n"
                            f"{r.stdout}\n{r.stderr}")
+    records = []
     for line in r.stdout.splitlines():
-        if line.startswith("RECORD "):
-            rec = json.loads(line[len("RECORD "):])
-            records.append(rec)
+        if not line.startswith("RECORD "):
+            continue
+        rec = json.loads(line[len("RECORD "):])
+        records.append(rec)
+        if rec["structural"]:
+            tag = "static" if rec["static_solid"] else "dynamic"
+            print(f"model_ici_bytes_per_site(shard={rec['shard']},{tag}),"
+                  f"{rec['model_ici_bytes_per_site']:.4f},B")
+        else:
             print(f"{rec['scenario']}_sps,{rec['sites_per_sec']:.3e},"
                   f"sites/s (exact={rec['bit_exact']})")
     return records
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

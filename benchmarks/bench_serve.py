@@ -35,7 +35,8 @@ index above threshold.  ``benchmarks/ci.sh`` gates on this record.
 numbers are shapes-and-gates, not performance); the full profile runs
 the sharded engine on a 2x2 fake-device mesh through the Pallas kernel
 (interpret mode on CPU -- wall clock only meaningful on real chips).
-Both run in a subprocess so XLA device flags never leak.
+Both run in a child process so XLA device flags never leak, and so the
+parent never touches JAX (on a TPU host the child holds the chips).
 
     PYTHONPATH=src python -m benchmarks.bench_serve          # full
     PYTHONPATH=src python -m benchmarks.bench_serve --smoke  # tiny/CI
@@ -59,6 +60,7 @@ SCRIPT = textwrap.dedent("""
         os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                    " --xla_force_host_platform_device_count=4")
     import jax
+    from repro.core.distributed import make_mesh
     from repro.serve import (CAServeEngine, FaultInjector, SimJob,
                              make_schedule)
     from repro.telemetry import Telemetry
@@ -66,7 +68,7 @@ SCRIPT = textwrap.dedent("""
     H, W = (16, 128) if smoke else (32, 256)
     slots, jobs, steps = (2, 4, 12) if smoke else (4, 8, 24)
     depth, frame_every = 2, 4
-    mesh = None if smoke else jax.make_mesh((2, 2), ("data", "model"))
+    mesh = None if smoke else make_mesh((2, 2), ("data", "model"))
 
     def run_profile(injector, ckpt_dir):
         # Private telemetry per profile: spans/counters isolated, JSONL
@@ -299,10 +301,10 @@ SCRIPT = textwrap.dedent("""
 """)
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
-    import jax
-    if smoke is None:
-        smoke = jax.default_backend() != "tpu"
+def main(smoke: bool = False) -> List[Dict]:
+    """Run the profiles in a child process and collect its records.
+    This process never touches JAX: on a TPU host the child is the one
+    process that holds the chips."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src" + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run(
@@ -336,4 +338,4 @@ def main(smoke: bool | None = None) -> List[Dict]:
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

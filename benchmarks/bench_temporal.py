@@ -42,10 +42,8 @@ def _time(fn, *args) -> float:
     return time.perf_counter() - t0
 
 
-def main(smoke: bool | None = None) -> List[Dict]:
+def main(smoke: bool = False) -> List[Dict]:
     backend = jax.default_backend()
-    if smoke is None:
-        smoke = backend != "tpu"
     h, w = SMOKE_SHAPE if smoke else FULL_SHAPE
     steps = 4 if smoke else 50
     wd = w // 32
@@ -132,4 +130,4 @@ def main(smoke: bool | None = None) -> List[Dict]:
 
 
 if __name__ == "__main__":
-    main(smoke=True if "--smoke" in sys.argv[1:] else None)
+    main(smoke="--smoke" in sys.argv[1:])

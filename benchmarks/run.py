@@ -125,12 +125,27 @@ def main(argv=None) -> None:
     import jax
 
     from repro import telemetry
+    from repro.launch import compile_cache
+    compile_cache.enable()
+    records = []
+
+    def run(name, mod):
+        print(f"== {name} ==")
+        t0 = time.time()
+        records.extend(mod.main(smoke=smoke) or [])
+        print(f"-- {name} done in {time.time() - t0:.1f}s --\n")
+
+    # These three sweep in a child process each, which needs the chips:
+    # run them before this process touches the backend and holds them.
+    for name, mod in [("distributed", bench_distributed),
+                      ("scenarios", bench_scenarios),
+                      ("serve", bench_serve)]:
+        run(name, mod)
     trace_ctx = contextlib.nullcontext()
     if profile:
         telemetry.configure(enabled=True,
                             jsonl_path="BENCH_telemetry.jsonl")
         trace_ctx = jax.profiler.trace("bench_trace")
-    records = []
     paper_benches = [] if smoke else [
         ("table1", bench_table1), ("fig9", bench_fig9),
         ("fig10", bench_fig10)]
@@ -142,23 +157,12 @@ def main(argv=None) -> None:
     with trace_ctx:
         for name, mod in [("kernel", bench_kernel),
                           ("temporal", bench_temporal),
-                          ("distributed", bench_distributed),
-                          ("scenarios", bench_scenarios),
-                          ("serve", bench_serve),
                           ("observables", bench_observables)]:
-            print(f"== {name} ==")
-            t0 = time.time()
-            records.extend(mod.main(smoke=smoke or None) or [])
-            print(f"-- {name} done in {time.time() - t0:.1f}s --\n")
-    # bench_temporal auto-degrades to the smoke profile on non-TPU
-    # backends even without --smoke, so the per-record "smoke"/"lattice"
-    # fields are authoritative; meta only records what was requested.
+            run(name, mod)
     out = {"meta": {"backend": jax.default_backend(),
                     "jax": jax.__version__,
                     "python": platform.python_version(),
-                    "smoke_requested": smoke,
-                    "smoke_profiles_present":
-                        sorted({bool(r.get("smoke")) for r in records})},
+                    "smoke": smoke},
            "headline": _headline(records),
            "records": records}
     if profile:

@@ -22,7 +22,7 @@ from repro.core import bitplane, byte_step, distributed  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = distributed.make_mesh((2, 2, 2), ("pod", "data", "model"))
     print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
     H, W, steps = 128, 1024, 16
     planes = bitplane.pack(jnp.asarray(

@@ -90,8 +90,7 @@ class ChecksumError(CheckpointError):
 
 
 def _flatten(tree):
-    # jax.tree.flatten_with_path only exists on newer jax releases.
-    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = {}
     for path, leaf in flat:
         key = "/".join(_SAFE.sub("_", str(getattr(p, "key", getattr(p, "idx", p))))

@@ -51,21 +51,24 @@ from repro import telemetry
 
 Axes = Union[str, Tuple[str, ...]]
 
-# ``jax.shard_map`` (with check_vma) only exists on newer jax; older
-# releases ship it as ``jax.experimental.shard_map`` (with check_rep).
-# Replication checking is off either way: pallas_call's out_shape carries
-# no replication metadata; correctness is established by the bit-exactness
-# tests.
-if hasattr(jax, "shard_map"):
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _experimental_smap
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _experimental_smap(f, mesh=mesh, in_specs=in_specs,
-                                  out_specs=out_specs, check_rep=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    # Replication checking is off: pallas_call's out_shape carries no
+    # replication metadata; correctness is established by the
+    # bit-exactness tests.
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """The device mesh every CA path runs on: ``jax.make_mesh`` with
+    ``Auto`` axis types.  (``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which the engine's ``device_put``, the ``psum``'d
+    moments and the GSPMD baseline's sharding constraints all fail.)"""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
+
 
 def _mesh_size(mesh, axes: Axes) -> int:
     """Static product of mesh extents over one axis name or a tuple."""

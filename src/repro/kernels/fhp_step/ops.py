@@ -49,6 +49,12 @@ COMPUTE_ROW_WEIGHT = 0.2
 
 MAX_STEPS_PER_LAUNCH = 8
 
+# The (sublane, lane) tiling of a 32-bit VMEM block on TPU: Mosaic only
+# compiles BlockSpecs whose last two dims are multiples of these or equal
+# to the array's own extents (``tile_error``).
+SUBLANES = 8
+LANES = 128
+
 
 def _pow2_ge(x: int) -> int:
     """Smallest power of two >= x (>= 1)."""
@@ -94,28 +100,61 @@ def vmem_bytes(bh: int, wd: int, steps: int = 1, block_words: int = 0,
     return total
 
 
+def tile_error(bh: int, bw: int, h: int, wd: int) -> str | None:
+    """Why Mosaic would refuse a ``(bh, bw)`` tile of an ``(h, wd)``
+    array, or None when it compiles: the last two dims of every
+    BlockSpec must be multiples of the (8, 128) sublane x lane tiling or
+    equal to the array's own extents."""
+    if bh % SUBLANES and bh != h:
+        return (f"block_rows={bh} is neither a multiple of {SUBLANES} nor "
+                f"the full height {h}")
+    if bw % LANES and bw != wd:
+        return (f"block_words={bw} is neither a multiple of {LANES} nor "
+                f"the full width {wd}")
+    return None
+
+
+def _bh_candidates(h: int | None):
+    """Band heights the chip compiles, tallest first: the powers of two
+    8..32 (dividing ``h`` when it is given), else the full extent."""
+    cands = [bh for bh in (32, 16, SUBLANES) if h is None or h % bh == 0]
+    return cands or [h]
+
+
+def _bw_candidates(width: int, divisors_only: bool):
+    """Word-block candidates for the joint tile search: the full width
+    (legacy 1-D row bands) plus descending powers of two from ``LANES``
+    up.  The periodic path needs ``bw | width``; the extended path pads,
+    so any bw goes."""
+    cands = [width]
+    bw = LANES
+    while bw * 2 < width:
+        bw *= 2
+    while LANES <= bw < width:
+        if not divisors_only or width % bw == 0:
+            cands.append(bw)
+        bw //= 2
+    return cands
+
+
 def _pick_bh(wd: int, steps: int, h: int | None, block_words: int = 0,
              static_solid: bool = False, n_planes: int = 8) -> int:
-    """Largest power-of-two band height (<=32) that admits the
+    """Tallest band height from ``_bh_candidates`` that admits the
     ``steps``-row halo, fits VMEM, and (when ``h`` is given) divides H."""
-    def ok(bh):
-        return ((h is None or h % bh == 0)
-                and vmem_bytes(bh, wd, steps, block_words, static_solid,
-                               n_planes) <= VMEM_BUDGET_BYTES)
-    bh = 32
-    while bh > steps and not ok(bh):
-        bh //= 2
-    if bh < steps or not ok(bh):
-        raise ValueError(f"no valid block for H={h}, Wd={wd}, "
-                         f"block_words={block_words}, "
-                         f"steps_per_launch={steps}")
-    return bh
+    for bh in _bh_candidates(h):
+        if bh >= steps and vmem_bytes(bh, wd, steps, block_words,
+                                      static_solid, n_planes
+                                      ) <= VMEM_BUDGET_BYTES:
+            return bh
+    raise ValueError(f"no valid block for H={h}, Wd={wd}, "
+                     f"block_words={block_words}, "
+                     f"steps_per_launch={steps}")
 
 
 def pick_block_rows(h: int, wd: int, steps: int = 1,
                     n_planes: int = 8) -> int:
-    """Largest power-of-two band height (<=32) that divides H, admits the
-    ``steps``-row halo, and fits VMEM."""
+    """Tallest band height (8, 16 or 32 dividing H, else H) that admits
+    the ``steps``-row halo and fits VMEM."""
     return _pick_bh(wd, steps, h, n_planes=n_planes)
 
 
@@ -131,25 +170,16 @@ def pick_tile_extended(wd: int, steps: int = 1,
                        static_solid: bool = False,
                        n_planes: int = 8) -> Tuple[int, int]:
     """``(block_rows, block_words)`` for the extended path: the legacy
-    full-width 1-D band when it fits VMEM, else the widest power-of-two
-    word block that admits the ``steps``-word x apron and fits (the
-    extended path word-pads the array to a block multiple, so ``bw`` need
-    not divide the width)."""
-    try:
-        return _pick_bh(wd, steps, None, static_solid=static_solid,
-                        n_planes=n_planes), wd
-    except ValueError:
-        pass
-    bw = 1
-    while bw * 2 < wd:
-        bw *= 2
-    while bw >= max(steps, 1):
+    full-width 1-D band when it fits VMEM, else the widest word block
+    from ``_bw_candidates`` that fits (the extended path word-pads the
+    array to a block multiple, so ``bw`` need not divide the width)."""
+    for bw in _bw_candidates(wd, divisors_only=False):
         try:
             return _pick_bh(wd, steps, None, block_words=bw,
                             static_solid=static_solid,
                             n_planes=n_planes), bw
         except ValueError:
-            bw //= 2
+            continue
     raise ValueError(f"no valid 2-D tile for Wd={wd}, "
                      f"steps_per_launch={steps}")
 
@@ -237,21 +267,6 @@ def sharded_launch_cost(bh: int, steps: int, depth: int,
         static_solid=static_solid, overlap=overlap)["total_s_per_site"]
 
 
-def _bw_candidates(width: int, divisors_only: bool):
-    """Word-block candidates for the joint tile search: the full width
-    (legacy 1-D row bands) plus descending powers of two.  The periodic
-    path needs ``bw | width``; the extended path pads, so any bw goes."""
-    cands = [width]
-    bw = 1
-    while bw * 2 < width:
-        bw *= 2
-    while bw >= 1:
-        if not divisors_only or width % bw == 0:
-            cands.append(bw)
-        bw //= 2
-    return cands
-
-
 def autotune_launch(h: int, wd: int, *, max_steps: int = MAX_STEPS_PER_LAUNCH,
                     vmem_budget: int = VMEM_BUDGET_BYTES,
                     max_depth: int | None = None,
@@ -280,6 +295,9 @@ def autotune_launch(h: int, wd: int, *, max_steps: int = MAX_STEPS_PER_LAUNCH,
     split's extra per-slice aprons -- overlap shifts the optimal depth
     because the exchange is then partially free, hence the joint search.
     Ties prefer ``overlap=False`` (the serial path is the simpler plan).
+    Every tile searched is one the chip compiles (``tile_error``):
+    ``block_rows`` from ``_bh_candidates``, ``block_words`` from
+    ``_bw_candidates``.
     The extended path has no divisibility constraint (rows and words are
     padded), but the T-row/T-word halo must fit the tile and the depth
     must fit the one-word x halo (depth <= 31).  ``block_words`` here is
@@ -303,20 +321,17 @@ def autotune_launch(h: int, wd: int, *, max_steps: int = MAX_STEPS_PER_LAUNCH,
     if max_depth is None:
         for bw in _bw_candidates(wd, divisors_only=True):
             x_blocked = bw < wd
-            bh = 32
-            while bh >= 1:
-                if h % bh == 0:
-                    t_cap = min(bh, max_steps, bw if x_blocked else bh)
-                    for steps in range(1, t_cap + 1):
-                        if vmem_bytes(bh, wd, steps, bw, n_planes=n_planes,
-                                      moments_words=moments_words
-                                      ) > vmem_budget:
-                            break
-                        cost = launch_cost(bh, steps, bw, wd,
-                                           moments_words=moments_words)
-                        if best_cost is None or cost < best_cost:
-                            best, best_cost = (bh, bw, steps), cost
-                bh //= 2
+            for bh in _bh_candidates(h):
+                t_cap = min(bh, max_steps, bw if x_blocked else bh)
+                for steps in range(1, t_cap + 1):
+                    if vmem_bytes(bh, wd, steps, bw, n_planes=n_planes,
+                                  moments_words=moments_words
+                                  ) > vmem_budget:
+                        break
+                    cost = launch_cost(bh, steps, bw, wd,
+                                       moments_words=moments_words)
+                    if best_cost is None or cost < best_cost:
+                        best, best_cost = (bh, bw, steps), cost
         if best is None:
             raise ValueError(f"no valid launch config for H={h}, Wd={wd}")
         return best
@@ -327,8 +342,7 @@ def autotune_launch(h: int, wd: int, *, max_steps: int = MAX_STEPS_PER_LAUNCH,
     we = wdl + 2                           # extended shard width in words
     for bw in _bw_candidates(we, divisors_only=False):
         x_blocked = bw < we
-        bh = 32
-        while bh >= 1:
+        for bh in _bh_candidates(None):
             # depth <= hl: the nearest-neighbour exchange cannot source a
             # deeper apron than one shard's rows (distributed.py asserts).
             for depth in range(1, min(max_depth, 31, hl) + 1):
@@ -351,7 +365,6 @@ def autotune_launch(h: int, wd: int, *, max_steps: int = MAX_STEPS_PER_LAUNCH,
                         if best_cost is None or cost < best_cost:
                             best, best_cost = (bh, bw, steps, depth,
                                                overlap), cost
-            bh //= 2
     if best is None:
         raise ValueError(f"no valid sharded launch config for "
                          f"hl={hl}, wdl={wdl}")
@@ -458,6 +471,10 @@ def fhp_step_pallas(planes: jnp.ndarray, t, *, p_force: float = 0.0,
                          f"(the extended path word-pads in run_extended)")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    if not interpret and (err := tile_error(bh, bw, h, wd)):
+        raise ValueError(f"{err}: the TPU compiler needs the last two "
+                         f"block dims to be multiples of ({SUBLANES}, "
+                         f"{LANES}) or the array's own extents")
     pq = prng.quantize_p(p_force)
 
     moment_kw = {}

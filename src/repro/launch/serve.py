@@ -5,7 +5,8 @@ fault injection and rollback-replay.
         --height 32 --width 128 --ckpt-dir /tmp/ca_ckpt
     PYTHONPATH=src python -m repro.launch.serve --jobs 8 --faults 3
 
-``--mesh ny nx`` runs the sharded engine on a fake-device mesh (set
+``--mesh ny nx`` runs the sharded engine on a mesh of that shape: the
+chips of a TPU host, or on CPU fake devices (set
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` accordingly);
 ``--faults SEED`` drives a seeded fault schedule (bit flips + garbaged
 shards + torn checkpoints) through the run and reports detection /
@@ -50,14 +51,13 @@ def main(argv=None) -> int:
                     help="arm overload degradation above this round wall")
     args = ap.parse_args(argv)
 
-    import jax
-
+    from repro.core.distributed import make_mesh
     from repro.serve import AdmissionError, CAServeEngine, FaultInjector, \
         SimJob, TenantConfig, make_schedule
 
     mesh = None
     if args.mesh:
-        mesh = jax.make_mesh(tuple(args.mesh), ("data", "model"))
+        mesh = make_mesh(args.mesh, ("data", "model"))
 
     ckpt_dir = args.ckpt_dir or tempfile.mkdtemp(prefix="ca_serve_")
     injector = None
@@ -131,4 +131,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     sys.exit(main())

@@ -272,13 +272,9 @@ def _mesh_fingerprint() -> tuple:
     """Static identity of the attached device topology: backend, device
     count, and device kind.  Cheap (no collectives) and stable for the
     process lifetime unless the platform itself is re-selected."""
-    try:
-        import jax
-        devs = jax.devices()
-        kind = getattr(devs[0], "device_kind", "?") if devs else "none"
-        return (jax.default_backend(), len(devs), kind)
-    except Exception:
-        return ("unavailable", 0, "?")
+    import jax
+    devs = jax.devices()
+    return (jax.default_backend(), len(devs), devs[0].device_kind)
 
 
 def measured_exchange_latency(refresh: bool = False) -> float:
@@ -289,7 +285,8 @@ def measured_exchange_latency(refresh: bool = False) -> float:
     best of 3 trials of 64 rounds -- and caches the per-round seconds
     under the mesh fingerprint (backend, device count, device kind), so
     repeated ``autotune_launch`` calls never re-run the microbench while
-    a topology change invalidates the cache naturally.
+    a topology change invalidates the cache naturally.  A failure of the
+    microbench raises: a constant in its place would hide a broken mesh.
     On CPU / single-device backends ``ppermute`` is a host memcpy whose
     timing says nothing about ICI, so the ``EXCHANGE_LATENCY_S`` constant
     is returned unchanged (keeps the model, the autotuner, and every test
@@ -297,33 +294,30 @@ def measured_exchange_latency(refresh: bool = False) -> float:
     key = _mesh_fingerprint()
     if key in _MEASURED_EXCHANGE_LATENCY and not refresh:
         return _MEASURED_EXCHANGE_LATENCY[key]
+    backend, n, _kind = key
     lat = EXCHANGE_LATENCY_S
-    try:
+    if backend != "cpu" and n >= 2:
         import jax
-        devs = jax.devices()
-        if jax.default_backend() != "cpu" and len(devs) >= 2:
-            import jax.numpy as jnp
-            from jax import lax
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        import jax.numpy as jnp
+        from jax import lax
+        from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from repro.core.distributed import _ring, _shard_map
+        from repro.core.distributed import _ring, _shard_map, make_mesh
 
-            n, rounds = len(devs), 64
-            mesh = jax.make_mesh((n,), ("x",))
+        rounds = 64
+        mesh = make_mesh((n,), ("x",))
 
-            def chain(x):
-                def body(_, v):
-                    return lax.ppermute(v, "x", _ring(n, up=True))
-                return lax.fori_loop(0, rounds, body, x)
+        def chain(x):
+            def body(_, v):
+                return lax.ppermute(v, "x", _ring(n, up=True))
+            return lax.fori_loop(0, rounds, body, x)
 
-            g = jax.jit(_shard_map(chain, mesh, (P("x"),), P("x")))
-            x = jax.device_put(jnp.zeros((8 * n, 128), jnp.float32),
-                               NamedSharding(mesh, P("x")))
-            g(x).block_until_ready()           # compile + warm
-            best = min(_timed(g, x) for _ in range(3))
-            lat = max(best / rounds, 1e-8)
-    except Exception:          # no mesh / no backend: keep the constant
-        lat = EXCHANGE_LATENCY_S
+        g = jax.jit(_shard_map(chain, mesh, (P("x"),), P("x")))
+        x = jax.device_put(jnp.zeros((8 * n, 128), jnp.float32),
+                           NamedSharding(mesh, P("x")))
+        g(x).block_until_ready()           # compile + warm
+        best = min(_timed(g, x) for _ in range(3))
+        lat = max(best / rounds, 1e-8)
     _MEASURED_EXCHANGE_LATENCY[key] = lat
     return lat
 

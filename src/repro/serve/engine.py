@@ -309,16 +309,13 @@ class CAServeEngine:
         round.  Wildly optimistic on an interpret-mode CPU (it prices a
         TPU) -- exactly what a *provable* infeasibility test wants
         before the first measured round replaces it."""
-        try:
-            from repro.roofline import analysis
-            t = max(int(self.steps_per_launch or 1), 1)
-            terms = analysis.sharded_fhp_traffic(
-                self.height, self.width // 32, depth=self.depth,
-                T=min(t, self.height), block_rows=self.height)
-            return (terms["total_s_per_site"] * self.height * self.width
-                    * self.depth)
-        except Exception:
-            return 0.0
+        from repro.roofline import analysis
+        t = max(int(self.steps_per_launch or 1), 1)
+        terms = analysis.sharded_fhp_traffic(
+            self.height, self.width // 32, depth=self.depth,
+            T=min(t, self.height), block_rows=self.height)
+        return (terms["total_s_per_site"] * self.height * self.width
+                * self.depth)
 
     def _job_rounds(self, job: SimJob) -> int:
         return -(-max(job.steps - job.steps_done, 0) // self.round_steps)

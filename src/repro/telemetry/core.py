@@ -54,11 +54,8 @@ __all__ = ["Telemetry", "configure", "default", "span", "count", "gauge",
 
 def _tracing() -> bool:
     """True while jax is tracing (inside jit/scan/shard_map staging)."""
-    try:
-        import jax
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class _NullSpan:

@@ -17,7 +17,7 @@ SCRIPT = textwrap.dedent("""
     failures = []
     for mesh_shape, axes in [((4, 2), ("data", "model")),
                              ((2, 2, 2), ("pod", "data", "model"))]:
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = distributed.make_mesh(mesh_shape, axes)
         y_axes = axes[:-1]
         H, W = 32, 256
         s = jnp.asarray(byte_step.make_channel(H, W, density=0.3, seed=3))

@@ -15,6 +15,7 @@ SCRIPT = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro import checkpoint as ckpt
     from repro.configs import get_smoke
+    from repro.core.distributed import make_mesh
     from repro.data.pipeline import SyntheticLM
     from repro.models import init_params, loss_fn
     from repro.optim import AdamW, cosine_schedule
@@ -40,13 +41,13 @@ SCRIPT = textwrap.dedent("""
     opt_state = opt.init(params)
 
     # mesh A: 4-way data x 2-way model
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     pa, oa, _ = run_steps(mesh_a, params, opt_state, 0, 2)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, 2, {"params": pa, "opt": oa})
 
         # "cluster shrinks": mesh B is 2-way data x 4-way model
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         shard_b = {"params": tree_shardings(mesh_b, pa, axes),
                    "opt": {"m": tree_shardings(mesh_b, pa, axes),
                            "v": tree_shardings(mesh_b, pa, axes),
@@ -98,7 +99,7 @@ CA_SCRIPT = textwrap.dedent("""
         return run(jax.device_put(p, sh), t0)
 
     # mesh A advances to t=4, checkpoints with the rule name in metadata
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = distributed.make_mesh((4, 2), ("data", "model"))
     mid = run_on(mesh_a, planes, 0, 4, name)
     with tempfile.TemporaryDirectory() as d:
         ckpt.save(d, 4, {"planes": mid}, meta={"rule": name, "t": 4})
@@ -108,7 +109,7 @@ CA_SCRIPT = textwrap.dedent("""
 
         # "cluster reshapes": restore onto a 2x4 mesh, continue under the
         # rule named by the checkpoint
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = distributed.make_mesh((2, 4), ("data", "model"))
         sh_b = NamedSharding(mesh_b,
                              distributed.lattice_spec(("data",), "model"))
         restored = ckpt.restore(d, step, {"planes": mid}, {"planes": sh_b})
@@ -148,7 +149,7 @@ CA_CORRUPT_SCRIPT = textwrap.dedent("""
     spec = rulespec.get_rule(name)
     planes = bitplane.pack(jnp.asarray(spec.init_bytes(H, W, 0.3, 9)),
                            n_planes=spec.n_planes)
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = distributed.make_mesh((4, 2), ("data", "model"))
     sh = NamedSharding(mesh, distributed.lattice_spec(("data",), "model"))
     run = jax.jit(distributed.make_run(
         mesh, 4, y_axes=("data",), x_axis="model", depth=2,

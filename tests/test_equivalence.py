@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # deterministic fallback, see _hypothesis_fallback.py
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bitplane, boolean, byte_step, prng, rules
 

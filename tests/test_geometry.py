@@ -7,10 +7,7 @@ composition invariants.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # deterministic fallback, see _hypothesis_fallback.py
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bitplane, rules
 from repro.geometry import (Disk, Empty, HalfPlane, ObstacleArray,

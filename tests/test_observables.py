@@ -236,7 +236,7 @@ SHARDED_SCRIPT = textwrap.dedent("""
     ms = rulespec.moment_spec(spec)
     rng = np.random.default_rng(0)
     p = jnp.asarray(rng.integers(0, 2**32, (8, 16, 4), dtype=np.uint32))
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = distributed.make_mesh((2, 2), ("data", "model"))
     for overlap in (False, True):
         run = distributed.make_run(mesh, 4, depth=2, p_force=0.05,
                                    use_pallas=True, steps_per_launch=2,

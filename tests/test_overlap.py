@@ -268,7 +268,7 @@ MESH_SCRIPT = textwrap.dedent("""
     from repro.core import bitplane, distributed
 
     failures = []
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = distributed.make_mesh((2, 2), ("data", "model"))
     sh = NamedSharding(mesh, distributed.lattice_spec(("data",), "model"))
     sc = scenarios.get("cylinder", height=16, width=256)
     p = sc.initial_planes()

@@ -22,10 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # deterministic fallback, see _hypothesis_fallback.py
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import bitplane, rulespec
 from repro.kernels.fhp_step.ops import run_extended, run_pallas
@@ -132,7 +129,7 @@ def test_fhp_rule_stepper_matches_bitplane():
 # Invariant audits on random states (property-based).
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10 ** 6))
 def test_conservation_on_random_states(seed):
     """Each rule's claimed conserved quantities hold on *arbitrary*
@@ -155,7 +152,7 @@ def test_conservation_on_random_states(seed):
             assert sum(before) == sum(after), (name, before, after)
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10 ** 6))
 def test_momentum_conservation_solid_free(seed):
     """Rules claiming momentum conservation keep (sum px2, sum py) on a

@@ -2,10 +2,7 @@
 plus the registry-wide audits (every rule in ``core.rulespec``)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # deterministic fallback, see _hypothesis_fallback.py
-    from _hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import rules
 

@@ -167,7 +167,7 @@ def test_static_solid_make_run_jnp_fallback_and_batched():
     import jax
 
     from repro.core import distributed
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = distributed.make_mesh((1, 1), ("data", "model"))
     sc = scenarios.get("cylinder", **TINY)
     p = sc.initial_planes()
     want = ref_steps(p, 4, p_force=sc.p_force)

@@ -247,10 +247,11 @@ _SHARDED_SCRIPT = textwrap.dedent("""
     import os, tempfile
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, numpy as np
+    from repro.core.distributed import make_mesh
     from repro.serve import CAServeEngine, Fault, FaultInjector, SimJob
 
     H, W = 16, 128
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
 
     def build(injector, d):
         eng = CAServeEngine(height=H, width=W, slots=2, depth=2,
