@@ -24,9 +24,9 @@ there.  On TPU the timed ``fused_vs_posthoc_speedup`` is the headline;
 off-TPU it is recorded but expected < 1 (see the interpret-mode caveat
 in EXPERIMENTS.md stage 10).
 
-The second record prices the telemetry layer's disabled path: library
-code is instrumented unconditionally (``telemetry.span`` at every layer
-boundary), so the no-op span must be nanoseconds.  The record carries
+The second record prices the telemetry layer's disabled path: the
+serve engine is instrumented unconditionally (``telemetry.span`` at each
+step of its round), so the no-op span must be nanoseconds.  The record carries
 the measured per-call cost and expresses it as a fraction of one fused
 CA step (``telemetry_overhead_frac``) at ~10 calls/round -- CI asserts
 the fraction stays negligible.
@@ -136,7 +136,7 @@ def main(smoke: bool = False) -> List[Dict]:
     per_call_s = max(0.0, dt_ins - dt_bare) / (2 * n)
     step_s = dt_fused / steps
     # ~10 instrumented boundaries fire per serve round (admit, kernel,
-    # exchange, audit, frames, retire, checkpoint + counters); price
+    # audit, frames, retire, checkpoint + counters); price
     # them against one CA step of the *smallest* timed lattice -- the
     # most adverse ratio this suite produces.
     frac = per_call_s * 10 / step_s

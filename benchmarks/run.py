@@ -2,7 +2,6 @@
 
     PYTHONPATH=src python -m benchmarks.run            # full sweep
     PYTHONPATH=src python -m benchmarks.run --smoke    # tiny CI profile
-    PYTHONPATH=src python -m benchmarks.run --smoke --profile  # + tracing
 
 Table 1  -> bench_table1  (Mups per implementation tier)
 Fig. 9   -> bench_fig9    (speedup over sequential analogue + v5e projection)
@@ -18,13 +17,6 @@ serve    -> bench_serve   (continuous-batching job engine under open-loop
 observables -> bench_observables (in-kernel fused moments vs post-hoc
              re-streaming, bit-exactness gate; disabled-telemetry no-op
              cost)
-
-``--profile`` turns the telemetry layer on for the sweep (JSONL sink
-``BENCH_telemetry.jsonl``, summary appended to the output JSON) and
-wraps the record-producing benches in ``jax.profiler.trace`` writing to
-``bench_trace/`` -- the ``telemetry.span`` names land on the HLO via
-``jax.named_scope``, so kernel/exchange/boundary regions are findable
-in the trace viewer.
 
 The kernel-shaped benches (kernel, temporal, distributed) also return
 machine-readable records; this driver persists them to
@@ -115,16 +107,12 @@ def _headline(records):
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else argv
     smoke = "--smoke" in argv
-    profile = "--profile" in argv
     from benchmarks import (bench_distributed, bench_fig9, bench_fig10,
                             bench_kernel, bench_observables,
                             bench_scenarios, bench_serve, bench_table1,
                             bench_temporal)
-    import contextlib
-
     import jax
 
-    from repro import telemetry
     from repro.launch import compile_cache
     compile_cache.enable()
     records = []
@@ -141,11 +129,6 @@ def main(argv=None) -> None:
                       ("scenarios", bench_scenarios),
                       ("serve", bench_serve)]:
         run(name, mod)
-    trace_ctx = contextlib.nullcontext()
-    if profile:
-        telemetry.configure(enabled=True,
-                            jsonl_path="BENCH_telemetry.jsonl")
-        trace_ctx = jax.profiler.trace("bench_trace")
     paper_benches = [] if smoke else [
         ("table1", bench_table1), ("fig9", bench_fig9),
         ("fig10", bench_fig10)]
@@ -154,20 +137,16 @@ def main(argv=None) -> None:
         t0 = time.time()
         mod.main()
         print(f"-- {name} done in {time.time() - t0:.1f}s --\n")
-    with trace_ctx:
-        for name, mod in [("kernel", bench_kernel),
-                          ("temporal", bench_temporal),
-                          ("observables", bench_observables)]:
-            run(name, mod)
+    for name, mod in [("kernel", bench_kernel),
+                      ("temporal", bench_temporal),
+                      ("observables", bench_observables)]:
+        run(name, mod)
     out = {"meta": {"backend": jax.default_backend(),
                     "jax": jax.__version__,
                     "python": platform.python_version(),
                     "smoke": smoke},
            "headline": _headline(records),
            "records": records}
-    if profile:
-        out["telemetry"] = telemetry.summary()
-        telemetry.default().flush()
     with open(BENCH_JSON, "w") as f:
         json.dump(out, f, indent=2)
     print(f"wrote {len(records)} records -> {BENCH_JSON}")

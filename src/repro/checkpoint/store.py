@@ -125,13 +125,6 @@ def save(directory: str, step: int, tree: Any, meta: Optional[dict] = None,
     between syscalls is the previous good copy destroyed without a
     complete replacement staged on disk.
     """
-    from repro import telemetry
-    with telemetry.span("checkpoint.save", step=step):
-        return _save(directory, step, tree, meta, overwrite)
-
-
-def _save(directory: str, step: int, tree: Any, meta: Optional[dict],
-          overwrite: bool) -> str:
     os.makedirs(directory, exist_ok=True)
     tmp = os.path.join(directory, f"tmp_{step}_{os.getpid()}")
     final = step_dir(directory, step)
@@ -284,14 +277,6 @@ def restore(directory: str, step: int, target_tree: Any,
     serve layer's parked-job lattices, loaded individually via
     :func:`load_leaf`).
     """
-    from repro import telemetry
-    with telemetry.span("checkpoint.restore", step=step):
-        return _restore(directory, step, target_tree, shardings, check,
-                        strict)
-
-
-def _restore(directory: str, step: int, target_tree: Any,
-             shardings: Any, check: bool, strict: bool = True) -> Any:
     path = step_dir(directory, step)
     manifest = _load_manifest(path)
     flat_t, treedef = jax.tree.flatten(target_tree)

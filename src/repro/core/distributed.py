@@ -47,7 +47,6 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import prng, rulespec
-from repro import telemetry
 
 Axes = Union[str, Tuple[str, ...]]
 
@@ -100,13 +99,12 @@ def _exchange_halo(planes, d: int, ny: int, nx: int, y_axes: Axes,
                    x_axis: str):
     """x halo first (one word each side), then y halo on the x-extended
     array -- the corner words ride along with the y rows."""
-    with telemetry.span("exchange", depth=d):
-        left = lax.ppermute(planes[..., -1:], x_axis, _ring(nx, up=True))
-        right = lax.ppermute(planes[..., :1], x_axis, _ring(nx, up=False))
-        ext = jnp.concatenate([left, planes, right], axis=-1)
-        top = lax.ppermute(ext[..., -d:, :], y_axes, _ring(ny, up=True))
-        bot = lax.ppermute(ext[..., :d, :], y_axes, _ring(ny, up=False))
-        return jnp.concatenate([top, ext, bot], axis=-2)
+    left = lax.ppermute(planes[..., -1:], x_axis, _ring(nx, up=True))
+    right = lax.ppermute(planes[..., :1], x_axis, _ring(nx, up=False))
+    ext = jnp.concatenate([left, planes, right], axis=-1)
+    top = lax.ppermute(ext[..., -d:, :], y_axes, _ring(ny, up=True))
+    bot = lax.ppermute(ext[..., :d, :], y_axes, _ring(ny, up=False))
+    return jnp.concatenate([top, ext, bot], axis=-2)
 
 
 def make_solid_cache(mesh, *, y_axes: Axes = ("data",),

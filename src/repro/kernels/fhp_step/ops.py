@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from repro.core import prng
 from repro.kernels.fhp_step import kernel as _k
 from repro.roofline import analysis as _roofline
-from repro import telemetry
 
 # v5e VMEM is ~128 MiB but a realistic per-kernel working-set budget is far
 # smaller; we keep the resident blocks (3 input bands + 1 output band +
@@ -681,23 +680,22 @@ def run_extended(ext: jnp.ndarray, steps: int, *, t0=0, p_force: float = 0.0,
     bounds = (steps, he - steps, 1, wde - 1)
     moms = []
     done = 0
-    with telemetry.span("kernel.extended", steps=steps, launches=len(sizes)):
-        for L, rs in zip(sizes, schedules):
-            if rs:
-                ext, m = fhp_step_pallas(
-                    ext, t0 + done, p_force=p_force, y0=y0, xw0=xw0,
-                    steps_per_launch=L, block_rows=bh, block_words=bw,
-                    extended=True, hg=hg, wdg=wdg, donate=donate,
-                    solid=solid_ext, record_steps=rs, moment_bounds=bounds,
-                    **kw)
-                moms.append(m)
-            else:
-                ext = fhp_step_pallas(
-                    ext, t0 + done, p_force=p_force, y0=y0, xw0=xw0,
-                    steps_per_launch=L, block_rows=bh, block_words=bw,
-                    extended=True, hg=hg, wdg=wdg, donate=donate,
-                    solid=solid_ext, **kw)
-            done += L
+    for L, rs in zip(sizes, schedules):
+        if rs:
+            ext, m = fhp_step_pallas(
+                ext, t0 + done, p_force=p_force, y0=y0, xw0=xw0,
+                steps_per_launch=L, block_rows=bh, block_words=bw,
+                extended=True, hg=hg, wdg=wdg, donate=donate,
+                solid=solid_ext, record_steps=rs, moment_bounds=bounds,
+                **kw)
+            moms.append(m)
+        else:
+            ext = fhp_step_pallas(
+                ext, t0 + done, p_force=p_force, y0=y0, xw0=xw0,
+                steps_per_launch=L, block_rows=bh, block_words=bw,
+                extended=True, hg=hg, wdg=wdg, donate=donate,
+                solid=solid_ext, **kw)
+        done += L
     if k:
         if moms:
             mom = jnp.concatenate(moms, axis=-2)
@@ -789,13 +787,11 @@ def run_extended_split(ext: jnp.ndarray, steps: int, *, t0=0,
             moms.append(m)
         return out
 
-    with telemetry.span("kernel.interior", steps=d):
-        interior = sub(slice(d, he - d), slice(1, wde - 1), d, 1)
-    with telemetry.span("kernel.boundary", steps=d):
-        top = sub(slice(0, 3 * d), slice(None), 0, 0)
-        bot = sub(slice(he - 3 * d, he), slice(None), he - 3 * d, 0)
-        left = sub(slice(d, he - d), slice(0, 3), d, 0)
-        right = sub(slice(d, he - d), slice(wde - 3, wde), d, wde - 3)
+    interior = sub(slice(d, he - d), slice(1, wde - 1), d, 1)
+    top = sub(slice(0, 3 * d), slice(None), 0, 0)
+    bot = sub(slice(he - 3 * d, he), slice(None), he - 3 * d, 0)
+    left = sub(slice(d, he - d), slice(0, 3), d, 0)
+    right = sub(slice(d, he - d), slice(wde - 3, wde), d, wde - 3)
 
     mid = jnp.concatenate([left[..., d:hl - d, 1:2],
                            interior[..., d:hl - d, 1:wdl - 1],

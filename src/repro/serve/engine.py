@@ -70,6 +70,18 @@ Overload-robustness layer (PR 10 -- what makes it *operable*):
   throughput, frame-gap percentiles, deadline misses, sheds/rejects,
   and the Jain fairness index over weighted per-tenant work.
 
+Telemetry (the ``Telemetry`` passed in, off by default): each round is
+a ``serve.round`` span with the children ``serve.admit`` (holding one
+``serve.place`` per placement: the job's planes built on the host and
+set into its lane on the device), ``serve.kernel`` (dispatch
+and the moments fetch, where the host waits for the device),
+``serve.audit``, ``serve.rollback``, ``serve.frames``, ``serve.retire``
+and ``serve.checkpoint``; a job's wait from submission to its first
+placement is a ``serve.queue`` interval.  Job spans carry ``rid``.  The
+spans change no program: the engine dispatches the same work with
+telemetry on or off.  Walls (submission, frames, finish, the round's
+wall) read ``time.perf_counter()``, the clock of the spans.
+
 A :class:`repro.serve.faults.FaultInjector` can be attached to drive the
 deterministic fault schedule (bit flips, garbaged shards, torn
 checkpoints, kills, stragglers, burst storms, poison pills) that the
@@ -147,8 +159,9 @@ class SimJob:
     with_momentum: bool = False
     segments: list = dataclasses.field(default_factory=list)  # [[t0, n]..]
     preemptions: int = 0
-    submitted_wall: float = 0.0
+    submitted_wall: float = 0.0                 # perf_counter clock
     enqueued_round: int = 0
+    placed_wall: Optional[float] = None         # first placement
     finished_wall: Optional[float] = None
     deadline_met: Optional[bool] = None
     frame_slo_violations: int = 0
@@ -161,6 +174,7 @@ class SimJob:
         m = {k: getattr(self, k) for k in
              ("rid", "scenario", "steps", "frame_every", "overrides")}
         m.update({k: getattr(self, k) for k in _JOB_META_FIELDS})
+        m["placed"] = self.placed_wall is not None
         return m
 
     @classmethod
@@ -348,7 +362,7 @@ class CAServeEngine:
         except _adm.AdmissionError as err:
             self._log_reject(job, err)
             raise
-        job.submitted_wall = time.monotonic()
+        job.submitted_wall = time.perf_counter()
         job.enqueued_round = self.round
         self.jobs[job.rid] = job
         self.sched.enqueue(tenant, job.rid)
@@ -451,7 +465,7 @@ class CAServeEngine:
         whose lane group is full may preempt a strictly-lower-priority
         lane (audited boundaries only); otherwise it keeps its queue
         position without blocking jobs bound for other groups."""
-        self._shed_unmeetable(time.monotonic())
+        self._shed_unmeetable(time.perf_counter())
         if self._stretching():
             self._shed_overload()
         if not len(self.sched):
@@ -523,32 +537,39 @@ class CAServeEngine:
     def _place_job(self, job: SimJob, g: _LaneGroup, lane: int, sc):
         """Admit into ``lane``: fresh jobs record their invariants;
         parked jobs resume from their bit-exact parked lattice in a new
-        ``(t0, steps)`` segment."""
-        t = self.round * self.round_steps
-        if job.status == PARKED and job.parked_state is not None:
-            planes = jnp.asarray(job.parked_state)
-            job.parked_state = None
-            self.stats["resumed"] += 1
-            self.tel.event("serve.resume", rid=job.rid, round=self.round,
-                           steps_done=job.steps_done)
-        else:
-            planes = sc.initial_planes()
-            job.admitted_t = t
-            job.steps_done = 0
-            job.segments = []
-            spec = g.spec
-            # Momentum is only conserved on a free torus without forcing.
-            job.with_momentum = bool(
-                spec.conserves_momentum and sc.p_force == 0.0
-                and not sc.solid_mask().any())
-            inv = rulespec.invariants(spec, planes,
-                                      with_momentum=job.with_momentum)
-            job.expected = {k: np.asarray(v).tolist()
-                            for k, v in inv.items()}
-        g.state = g._place(g.state.at[lane].set(planes))
-        job.status, job.lane = RUNNING, lane
-        job.segments.append([t, 0])
-        g.slots[lane] = job
+        ``(t0, steps)`` segment.  A job's first placement ends its wait
+        in the queue (the ``serve.queue`` interval, from submission)."""
+        tel = self.tel
+        if job.placed_wall is None:
+            job.placed_wall = time.perf_counter()
+            tel.interval("serve.queue", job.submitted_wall, job.placed_wall,
+                         rid=job.rid)
+        with tel.span("serve.place", rid=job.rid):
+            t = self.round * self.round_steps
+            if job.status == PARKED and job.parked_state is not None:
+                planes = jnp.asarray(job.parked_state)
+                job.parked_state = None
+                self.stats["resumed"] += 1
+                tel.event("serve.resume", rid=job.rid, round=self.round,
+                          steps_done=job.steps_done)
+            else:
+                planes = sc.initial_planes()
+                job.admitted_t = t
+                job.steps_done = 0
+                job.segments = []
+                spec = g.spec
+                # Momentum is only conserved on a free torus without forcing.
+                job.with_momentum = bool(
+                    spec.conserves_momentum and sc.p_force == 0.0
+                    and not sc.solid_mask().any())
+                inv = rulespec.invariants(spec, planes,
+                                          with_momentum=job.with_momentum)
+                job.expected = {k: np.asarray(v).tolist()
+                                for k, v in inv.items()}
+            g.state = g._place(g.state.at[lane].set(planes))
+            job.status, job.lane = RUNNING, lane
+            job.segments.append([t, 0])
+            g.slots[lane] = job
 
     # ------------------------------------------------------------------
     # The round loop
@@ -561,12 +582,12 @@ class CAServeEngine:
         faults, audit, recover or stream/retire/checkpoint."""
         rnd = self.round
         tel = self.tel
-        t_wall = time.monotonic()
+        t_wall = time.perf_counter()
         try:
             with tel.span("serve.round", round=rnd):
                 self._tick_body(rnd, tel)
         finally:
-            self._observe_round(time.monotonic() - t_wall)
+            self._observe_round(time.perf_counter() - t_wall)
 
     def _tick_body(self, rnd: int, tel):
         if self.injector is not None:
@@ -578,13 +599,12 @@ class CAServeEngine:
         for g in self.groups.values():
             if not g.live_jobs():
                 continue
+            # Dispatch, then the moments fetch: where the host waits for
+            # the device.
             with tel.span("serve.kernel", group=g.key(),
                           steps=self.round_steps):
-                state, mom = g.run(g.state, t)
-                if tel.enabled:
-                    jax.block_until_ready(state)
-            g.state = state
-            g.last_moments = np.asarray(mom[..., -1, :])
+                g.state, mom = g.run(g.state, t)
+                g.last_moments = np.asarray(mom[..., -1, :])
             g.moments_dirty = False
             if self.injector is not None:
                 host = np.asarray(g.state)
@@ -927,7 +947,7 @@ class CAServeEngine:
                 g.state = g._place(g.state.at[lane].set(jnp.uint32(0)))
                 if first_finish:    # replays re-retire; count jobs once
                     self.stats["jobs_done"] += 1
-                    job.finished_wall = time.monotonic()
+                    job.finished_wall = time.perf_counter()
                     if job.deadline_s is not None:
                         job.deadline_met = (
                             job.finished_wall - job.submitted_wall
@@ -1046,7 +1066,7 @@ class CAServeEngine:
         queued resume queued, parked jobs resume parked (their lattices
         are checkpoint leaves), running jobs replay from the audited
         anchor bit-exactly, and the lifetime ``stats`` counters carry
-        over.  Deadline clocks restart at resume (the monotonic epoch
+        over.  Deadline clocks restart at resume (the perf_counter epoch
         does not survive the process)."""
         step = store.latest_valid_step(ckpt_dir)
         assert step is not None, f"no valid checkpoint under {ckpt_dir}"
@@ -1061,10 +1081,12 @@ class CAServeEngine:
         for k, v in meta.get("stats", {}).items():
             if k in eng.stats and not isinstance(eng.stats[k], list):
                 eng.stats[k] = v
-        now = time.monotonic()
+        now = time.perf_counter()
         for m in meta["jobs"]:
             job = SimJob.from_meta(m)
             job.submitted_wall = now
+            # A job placed before the crash has ended its queue wait.
+            job.placed_wall = now if m.get("placed") else None
             eng.jobs[job.rid] = job
         for k, ginfo in meta["groups"].items():
             eng.groups[k] = _LaneGroup(eng, ginfo["variant"],

@@ -1,12 +1,15 @@
-"""Telemetry layer: spans, counters, gauges; JSONL sink + summary rollup.
+"""Telemetry layer: host spans on the profiler's clock, counters, gauges;
+JSONL sink + summary rollup.
 
-See :mod:`repro.telemetry.core`.  Library code instruments against the
-module-level default instance (``telemetry.span("exchange")``), which is
-disabled -- a true no-op -- until ``telemetry.configure(...)`` turns it
-on (the serve engine and ``benchmarks/run.py --profile`` both do).
+See :mod:`repro.telemetry.core`.  The serve engine instruments against
+the ``Telemetry`` it is given (the module default when none is), which
+is disabled -- a true no-op -- until enabled; ``telemetry.configure(...)``
+turns the module default on.  While enabled, each span also enters a
+``jax.profiler.TraceAnnotation``, so a profiler trace shows it beside
+the device's operations.
 """
 from repro.telemetry.core import (Telemetry, configure, count, default,
-                                  event, gauge, span, span_stats, summary)
+                                  event, gauge, span, summary)
 
 __all__ = ["Telemetry", "configure", "count", "default", "event", "gauge",
-           "span", "span_stats", "summary"]
+           "span", "summary"]

@@ -1,14 +1,20 @@
-"""Lightweight telemetry: monotonic-clock spans, counters, gauges.
+"""Lightweight telemetry: host spans on the profiler's clock, counters,
+gauges, events.
 
-The serve/kernel stack built exchange overlap, audits, and
-rollback-replay with zero metrics -- nothing recorded how often
-rollbacks fire or where a round's latency budget goes.  This module is
-the measurement layer those systems hang their numbers on:
+The serve/kernel stack hangs its operational numbers on this module:
 
-* ``span(name, **attrs)`` -- a context manager timing one operation on
-  the monotonic clock, with thread-local nesting (child spans carry
-  their parent's id, so a ``serve.round`` decomposes into its
-  ``exchange`` / ``kernel`` / ``audit`` / ``checkpoint`` children);
+* ``span(name, **attrs)`` -- a context manager timing one host
+  operation on ``time.perf_counter()`` (the clock of the serve engine's
+  frame log), with thread-local nesting: each record keeps its name,
+  start, end, parent and attrs, so a ``serve.round`` decomposes into
+  its ``serve.admit`` / ``serve.kernel`` / ``serve.audit`` / ...
+  children.  The span also enters ``jax.profiler.TraceAnnotation(name,
+  **attrs)``, so a profiler trace taken meanwhile holds it on its host
+  plane, on the clock the device's operations use: an idle gap of the
+  device can be put down to the span the host was in;
+* ``interval(name, start, end, **attrs)`` -- a finished interval that
+  is no nested context (a job's wait in the queue, from submission to
+  placement);
 * ``count(name, n)`` / ``gauge(name, value)`` -- monotone event tallies
   and last-value measurements;
 * ``event(name, critical=False, **attrs)`` -- a point-in-time record;
@@ -16,45 +22,47 @@ the measurement layer those systems hang their numbers on:
   JSONL sink, so the trace of a fault survives the process death that
   ``CAServeEngine.resume`` recovers from.
 
-Sinks: an in-memory registry (bounded; ``summary()`` rolls spans up to
-count/total/p50/p99/max) and an optional JSONL file -- one
-self-describing object per line (``kind``: span | counter | gauge |
-event), opened line-buffered so every record is its own ``write()``.
+Sinks: an in-memory registry (bounded per span name; ``summary()`` rolls
+spans up to count/total/p50/p95/p99/max, ``records()`` returns them)
+and an optional JSONL file -- one self-describing object per line
+(``kind``: span | counter | gauge | event), opened line-buffered so
+every record is its own ``write()``.
 
 Disabled telemetry is a **true no-op**: ``span`` hands back a shared
-null context manager and ``count``/``gauge``/``event`` return before
-touching any state -- no clock reads, no allocation beyond the call
-itself, and (asserted in tests) no numeric change to instrumented code.
+null context manager and ``interval``/``count``/``gauge``/``event``
+return before touching any state -- no clock read, no annotation, no
+allocation beyond the call itself.
 
-Inside ``jit`` tracing, wall-clocking the span body would time *trace*
-time, not run time -- and a jitted region re-runs without re-tracing.
-A span opened while tracing therefore wraps the body in
-``jax.named_scope`` instead: the name lands on the HLO ops, so it shows
-up in ``jax.profiler.trace`` timelines (``benchmarks/run.py
---profile``), and the span is recorded with ``traced: true`` and the
-trace-time duration (compile-side cost, not step time -- consumers
-filter on the flag).
+A span opened while jax traces (inside ``jit``) is the null span too:
+the body of a jitted function runs once at trace time, not per call, so
+a clock there would time tracing.  Instrumented code therefore compiles
+to the same program whether telemetry is on or off.
 
 The module-level default instance is what library code instruments
-against (``telemetry.span(...)`` at layer boundaries); ``configure()``
-switches it on and points it at a sink.  Constructing private
-``Telemetry`` instances keeps tests and engines isolated.
+against; ``configure()`` switches it on and points it at a sink.
+Constructing private ``Telemetry`` instances keeps tests and engines
+isolated.
 """
 from __future__ import annotations
 
 import json
+import math
 import os
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+import jax
 
 __all__ = ["Telemetry", "configure", "default", "span", "count", "gauge",
-           "event", "summary", "span_stats"]
+           "event", "summary"]
+
+# (start, end, parent, attrs) of one span, on time.perf_counter().
+_Record = Tuple[float, float, Optional[str], Dict]
 
 
 def _tracing() -> bool:
     """True while jax is tracing (inside jit/scan/shard_map staging)."""
-    import jax
     return not jax.core.trace_ctx.is_top_level()
 
 
@@ -73,8 +81,9 @@ _NULL = _NullSpan()
 
 
 class _Span:
-    """One live monotonic-clock span; records itself on exit."""
-    __slots__ = ("_tel", "name", "attrs", "t0", "_parent")
+    """One live span: a profiler annotation plus a perf_counter interval,
+    recorded on exit."""
+    __slots__ = ("_tel", "name", "attrs", "start", "_parent", "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: Dict):
         self._tel = tel
@@ -85,69 +94,43 @@ class _Span:
         stack = self._tel._stack()
         self._parent = stack[-1] if stack else None
         stack.append(self.name)
-        self.t0 = time.monotonic()
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self.attrs)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        dur = time.monotonic() - self.t0
+        end = time.perf_counter()
+        self._annotation.__exit__(*exc)
         self._tel._stack().pop()
-        self._tel._record_span(self.name, dur, self._parent, self.attrs,
-                               traced=False)
-        return False
-
-
-class _TracedSpan:
-    """Span opened during jax tracing: names the HLO region
-    (``jax.named_scope`` -- visible in profiler traces) and records the
-    *trace-time* duration with ``traced: true``."""
-    __slots__ = ("_tel", "name", "attrs", "t0", "_scope", "_parent")
-
-    def __init__(self, tel: "Telemetry", name: str, attrs: Dict):
-        self._tel = tel
-        self.name = name
-        self.attrs = attrs
-
-    def __enter__(self):
-        import jax
-        stack = self._tel._stack()
-        self._parent = stack[-1] if stack else None
-        stack.append(self.name)
-        self._scope = jax.named_scope(self.name)
-        self._scope.__enter__()
-        self.t0 = time.monotonic()
-        return self
-
-    def __exit__(self, *exc):
-        dur = time.monotonic() - self.t0
-        self._scope.__exit__(*exc)
-        self._tel._stack().pop()
-        self._tel._record_span(self.name, dur, self._parent, self.attrs,
-                               traced=True)
+        self._tel._record_span(self.name, self.start, end, self._parent,
+                               self.attrs)
         return False
 
 
 class Telemetry:
     """Span/counter/gauge registry with an optional JSONL sink.
 
-    ``max_events`` bounds the in-memory per-span duration lists (oldest
-    halved out) so a long-lived serve process cannot grow without bound;
-    the JSONL sink, when given, keeps the full stream.
+    ``max_events`` bounds the in-memory records of each span name and
+    the event list (oldest halved out), so a long-lived serve process
+    cannot grow without bound; the JSONL sink, when given, keeps the
+    full stream.
     """
 
     def __init__(self, enabled: bool = False,
                  jsonl_path: Optional[str] = None,
                  max_events: int = 65536):
-        self.enabled = enabled
         self.max_events = int(max_events)
         self._lock = threading.Lock()
         self._local = threading.local()
-        self._durs: Dict[str, List[float]] = {}
-        self._traced: Dict[str, int] = {}
+        self._spans: Dict[str, List[_Record]] = {}
         self._counters: Dict[str, float] = {}
         self._gauges: Dict[str, float] = {}
         self._events: List[Dict] = []
         self._file = None
         self.jsonl_path = None
+        self.enabled = enabled
         if jsonl_path is not None:
             self.open_sink(jsonl_path)
 
@@ -178,31 +161,47 @@ class Telemetry:
         return st
 
     def span(self, name: str, **attrs):
-        """Context manager timing ``name``; the disabled path returns a
-        shared null object (no clock read, no allocation of state)."""
-        if not self.enabled:
+        """Context manager timing ``name``; the disabled path, and any
+        span opened while jax traces, return a shared null object (no
+        clock read, no annotation, no record)."""
+        if not self.enabled or _tracing():
             return _NULL
-        if _tracing():
-            return _TracedSpan(self, name, attrs)
         return _Span(self, name, attrs)
 
-    def _record_span(self, name: str, dur: float, parent: Optional[str],
-                     attrs: Dict, traced: bool) -> None:
+    def interval(self, name: str, start: float, end: float,
+                 **attrs) -> None:
+        """Record a finished interval ``[start, end]`` on
+        ``time.perf_counter()``: a span that is no nested context, so it
+        has no parent and no profiler annotation."""
+        if not self.enabled:
+            return
+        self._record_span(name, start, end, None, attrs)
+
+    def _record_span(self, name: str, start: float, end: float,
+                     parent: Optional[str], attrs: Dict) -> None:
         with self._lock:
-            if traced:
-                self._traced[name] = self._traced.get(name, 0) + 1
-            else:
-                d = self._durs.setdefault(name, [])
-                d.append(dur)
-                if len(d) > self.max_events:
-                    del d[:len(d) // 2]
+            recs = self._spans.setdefault(name, [])
+            recs.append((start, end, parent, attrs))
+            if len(recs) > self.max_events:
+                del recs[:len(recs) // 2]
             rec = {"kind": "span", "name": name, "wall": time.time(),
-                   "dur_s": dur, "traced": traced}
+                   "dur_s": end - start, "traced": False}
             if parent:
                 rec["parent"] = parent
             if attrs:
                 rec["attrs"] = attrs
             self._emit(rec)
+
+    def records(self, name: Optional[str] = None) -> List[Dict]:
+        """The span records held in memory (``name`` alone, or all), in
+        start order: ``{name, start, end, parent, attrs}``."""
+        with self._lock:
+            out = [{"name": n, "start": s, "end": e, "parent": p,
+                    "attrs": a}
+                   for n, recs in self._spans.items()
+                   if name is None or n == name
+                   for s, e, p, a in recs]
+        return sorted(out, key=lambda r: r["start"])
 
     # -- counters / gauges / events -----------------------------------------
     def count(self, name: str, n: float = 1) -> None:
@@ -241,42 +240,26 @@ class Telemetry:
 
     # -- rollup -------------------------------------------------------------
     def summary(self) -> Dict:
-        """Percentile rollup of everything recorded so far: per-span
-        ``{count, total_s, p50_s, p99_s, max_s}`` (wall spans only;
-        traced spans roll up as a count), counters, gauges."""
+        """Rollup of everything recorded so far: per span name
+        ``{count, total_s, p50_s, p95_s (nearest rank), p99_s, max_s}``,
+        counters, gauges, the number of events."""
         with self._lock:
             spans = {}
-            for name, durs in self._durs.items():
-                d = sorted(durs)
+            for name, recs in self._spans.items():
+                d = sorted(e - s for s, e, _, _ in recs)
                 n = len(d)
                 spans[name] = {
                     "count": n,
                     "total_s": sum(d),
                     "p50_s": d[(n - 1) // 2],
+                    "p95_s": d[max(math.ceil(0.95 * n), 1) - 1],
                     "p99_s": d[min(n - 1, (99 * n) // 100)],
                     "max_s": d[-1],
                 }
-            for name, n in self._traced.items():
-                spans.setdefault(name, {}).update(traced_count=n)
             return {"spans": spans,
                     "counters": dict(self._counters),
                     "gauges": dict(self._gauges),
                     "events": len(self._events)}
-
-    def span_stats(self, name: str) -> Optional[Dict]:
-        """Rollup for one span name -- ``{count, p50_s, p99_s, max_s}``
-        or None if never recorded.  The serve layer's straggler detector
-        and SLO report read single spans this way without paying for the
-        full :meth:`summary` walk."""
-        with self._lock:
-            durs = self._durs.get(name)
-            if not durs:
-                return None
-            d = sorted(durs)
-            n = len(d)
-            return {"count": n, "p50_s": d[(n - 1) // 2],
-                    "p99_s": d[min(n - 1, (99 * n) // 100)],
-                    "max_s": d[-1]}
 
     def events(self, name: Optional[str] = None) -> List[Dict]:
         with self._lock:
@@ -291,8 +274,7 @@ class Telemetry:
 
     def reset(self) -> None:
         with self._lock:
-            self._durs.clear()
-            self._traced.clear()
+            self._spans.clear()
             self._counters.clear()
             self._gauges.clear()
             self._events.clear()
@@ -341,6 +323,3 @@ def event(name: str, critical: bool = False, **attrs) -> None:
 def summary() -> Dict:
     return _default.summary()
 
-
-def span_stats(name: str) -> Optional[Dict]:
-    return _default.span_stats(name)
