@@ -4,9 +4,9 @@
 ``core.bitplane.step_planes`` (bit-identical given the same
 ``t / p_force / y0 / xw0``) that also accepts a leading ensemble batch
 axis and ``steps_per_launch`` = T fused steps per kernel launch;
-``run_pallas`` advances many steps with a donated carry, launching the
-multi-step kernel ``steps // T`` times (plus one ``steps % T``-step
-remainder launch).  ``run_extended`` is the shard-map hot path: it
+``run_pallas`` advances many steps, the state alternating between two
+buffers, launching the multi-step kernel ``steps // T`` times (plus one
+``steps % T``-step remainder launch).  ``run_extended`` is the shard-map hot path: it
 advances a halo-extended shard array ``depth`` steps in ceil(depth/T)
 donated launches with **global**-coordinate RNG (mod ``hg``/``wdg``), so
 one depth-``d`` exchange feeds ``d`` in-kernel steps.
@@ -533,12 +533,19 @@ def _launch_schedule(sizes, offset: int, k: int):
 def run_pallas(planes: jnp.ndarray, steps: int, *, p_force: float = 0.0,
                t0=0, steps_per_launch: int = 1,
                moments_every: int = 0, **kw) -> jnp.ndarray:
-    """Advance ``steps`` fused steps (fori_loop carry, donable).
+    """Advance ``steps`` fused steps.
 
     With ``steps_per_launch`` = T > 1 the plane stack crosses HBM once per
     T steps; the ``steps % T`` trailing steps run as **one** launch with
     ``steps_per_launch = rem`` (one more HBM round trip, not rem of them).
     Bit-identical to the T=1 path for any T (equivalence-tested).
+
+    The first launch reads the caller's array; the later ones run in a
+    ``fori_loop`` unrolled by two, so the state alternates between two
+    buffers, each launch writing the one its predecessor read.  A loop
+    body of one launch would make XLA copy the whole carry before every
+    launch (and the caller's array into the loop): a multi-band launch
+    cannot write over the array it reads (``kernel``'s module docstring).
 
     ``moments_every`` = k > 0 switches on fused observables and returns
     ``(planes, moments)``: the rule's ``MomentSpec`` reductions after
@@ -576,7 +583,9 @@ def run_pallas(planes: jnp.ndarray, steps: int, *, p_force: float = 0.0,
         return fhp_step_pallas(s, t0 + i * T, p_force=p_force,
                                steps_per_launch=T, **kw)
 
-    out = jax.lax.fori_loop(0, full, body, planes)
+    out = planes
+    if full:
+        out = jax.lax.fori_loop(1, full, body, body(0, planes), unroll=2)
     if rem:
         out = fhp_step_pallas(out, t0 + full * T, p_force=p_force,
                               steps_per_launch=rem, **kw)

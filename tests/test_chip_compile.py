@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -72,11 +73,14 @@ def _compile(fn, *args):
     it and that it fits one chip's HBM."""
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    mem = compiled.memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    assert used < V5E_HBM_BYTES, used
+    assert _hbm_used(compiled) < V5E_HBM_BYTES, _hbm_used(compiled)
     return compiled
+
+
+def _hbm_used(compiled):
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
 
 
 def _legal(bh, bw, h, wd):
@@ -170,6 +174,36 @@ def test_illegal_explicit_tile_refused(bh, bw):
         ops.fhp_step_pallas.lower(
             jax.ShapeDtypeStruct((8, 96, 512), jnp.uint32), 0,
             block_rows=bh, block_words=bw, interpret=False)
+
+
+# ---------------------------------------------------------------------------
+# The single-chip entry: launches after the first alternate two buffers
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("steps", [64, 60])
+@pytest.mark.parametrize("variant,p_force", [("fhp2", 0.05), ("bml", 0.0)])
+def test_single_chip_run_copies_no_state(on_tpu, one_chip, variant, p_force,
+                                         steps):
+    """The single-chip entry at the autotuner's tile on 8192 x 8192
+    sites: 8 launches of T=8, or 7 and a 4-step remainder.  The state
+    alternates between two buffers, so the compiled program holds no
+    copy of the whole state (a one-launch loop body would copy it before
+    every launch, and the caller's array once) and needs no more than
+    three states of HBM."""
+    h, wd = 8192, 256
+    n = rulespec.get_rule(variant).n_planes
+    bh, bw, T = ops.autotune_launch(h, wd, n_planes=n)
+    assert T == 8, T
+    run, _ = distributed.make_ensemble_run(
+        None, steps, variant=variant, p_force=p_force, use_pallas=True,
+        steps_per_launch=T, block_rows=bh, block_words=bw)
+    compiled = _compile(run, _spec((1, n, h, wd), one_chip),
+                        _spec((), one_chip, jnp.int32))
+    state = f"u32[1,{n},{h},{wd}]"
+    copies = re.findall(rf"%\S+ = {re.escape(state)}\S* copy\(\S+\)",
+                        compiled.as_text())
+    assert not copies, copies
+    assert _hbm_used(compiled) <= 3 * n * h * wd * 4, _hbm_used(compiled)
 
 
 # ---------------------------------------------------------------------------

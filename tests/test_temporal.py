@@ -46,9 +46,13 @@ def test_temporal_block_sharded_offsets(T, y0, xw0):
     assert bool((out_k == out_r).all()), (T, y0, xw0)
 
 
-@pytest.mark.parametrize("steps,T", [(5, 2), (7, 4), (3, 4)])
+@pytest.mark.parametrize("steps,T", [(5, 2), (7, 4), (3, 4),
+                                     (4, 4), (8, 4), (12, 4), (8, 2),
+                                     (13, 4), (9, 2)])
 def test_temporal_remainder_steps(steps, T):
-    """steps % T != 0: the trailing steps run as single-step launches."""
+    """1 to 4 full launches, with and without a ``steps % T`` remainder
+    launch: the first launch reads the input, the later ones alternate
+    two buffers, and the trailing steps run as one shorter launch."""
     p = state(16, 64, seed=7)
     out_k = run_pallas(p, steps, p_force=0.02, steps_per_launch=T,
                        block_rows=8)
