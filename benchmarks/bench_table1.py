@@ -58,7 +58,7 @@ def run() -> dict:
         def step(s, t):
             s = byte_step.stream_bytes(s)
             pl = [(s >> i) & 1 for i in range(8)]
-            chi = prng.chirality_bits((H, W), t)
+            chi = prng.random_bits((H, W), t, 1)
             out = boolean.collide_planes(pl, chi)
             s = sum((out[i].astype(jnp.uint8) << i) for i in range(8))
             acc = prng.bernoulli((H, W), t, P_FORCE)

@@ -104,7 +104,7 @@ def _as_plane_list(planes: jnp.ndarray) -> List[jnp.ndarray]:
     return [planes[..., k, :, :] for k in range(8)]
 
 
-def collide(planes: jnp.ndarray, chi: jnp.ndarray,
+def collide(planes: jnp.ndarray, chi,
             variant: str = "fhp2") -> jnp.ndarray:
     return jnp.stack(boolean.collide_planes(_as_plane_list(planes), chi,
                                             variant), axis=-3)
@@ -119,12 +119,16 @@ def step_planes(planes: jnp.ndarray, t, p_force: float = 0.0,
     offset both the RNG counters and the row parity, so a shard reproduces
     the global lattice bit-for-bit.  ``chi``/``accel`` override the
     counter-based RNG (used by equivalence tests to drive byte and
-    bit-plane paths with identical randomness).
+    bit-plane paths with identical randomness); ``chi`` is the chirality
+    word for FHP-II, the pair ``(c0, c1)`` for FHP-III.
     """
     shape_words = planes.shape[-2:]
     s = stream_planes(planes, row0=y0)
     if chi is None:
-        chi = prng.chirality_words(shape_words, t, y0=y0, xw0=xw0)
+        words = prng.random_words(shape_words, t,
+                                  rules.RANDOM_WORDS[variant],
+                                  y0=y0, xw0=xw0)
+        chi = words[0] if len(words) == 1 else words
     s = collide(s, chi, variant)
     if p_force or accel is not None:
         if accel is None:

@@ -29,7 +29,8 @@ _FORCE_XOR = np.uint8((1 << 0) | (1 << 3))  # swap W-mover into E-mover
 
 
 def lut_array(variant: str = "fhp2") -> jnp.ndarray:
-    """The (512,) uint8 collision LUT, index = chirality << 8 | state."""
+    """The flat uint8 collision LUT, index = r << 8 | state, with ``r``
+    the site's random bits (``rules.build_lut``)."""
     return jnp.asarray(rules.lut_flat(variant))
 
 
@@ -55,7 +56,8 @@ def stream_bytes(state: jnp.ndarray, row0=0) -> jnp.ndarray:
 
 def collide_bytes(state: jnp.ndarray, chi: jnp.ndarray,
                   variant: str = "fhp2") -> jnp.ndarray:
-    """Scattering step via the 2x256 LUT; ``chi`` is the per-node chirality bit."""
+    """Scattering step via the LUT; ``chi`` is the per-node random index
+    ``r`` (FHP-II: the chirality bit; FHP-III: ``c0 + 2 * c1``)."""
     idx = chi.astype(jnp.int32) * 256 + state.astype(jnp.int32)
     return jnp.take(lut_array(variant), idx, axis=0)
 
@@ -74,12 +76,14 @@ def step_bytes(state: jnp.ndarray, t, p_force: float = 0.0,
 
     ``t`` may be traced (step counter).  ``y0/x0`` offset the counter-based
     RNG so a shard of a larger lattice reproduces the global stream.
-    ``chi``/``accel`` override the RNG (equivalence tests).
+    ``chi``/``accel`` override the RNG (equivalence tests); ``chi`` is the
+    per-node random index of ``collide_bytes``.
     """
     shape = state.shape
     s = stream_bytes(state, row0=y0)
     if chi is None:
-        chi = prng.chirality_bits(shape, t, y0=y0, x0=x0)
+        chi = prng.random_bits(shape, t, rules.RANDOM_WORDS[variant],
+                               y0=y0, x0=x0)
     s = collide_bytes(s, chi, variant)
     if p_force or accel is not None:
         if accel is None:

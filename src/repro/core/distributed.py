@@ -272,12 +272,11 @@ def make_sharded_stepper(mesh, *, y_axes: Axes = ("data",),
         row0 = iy * hl - d  # parity offset (global H is even; sign-safe)
 
         def one(s, tt):
-            chi = (prng.word_u32_at(rows, cols, tt, salt=0x11)
-                   if rule.needs_rng else None)
+            words = prng.random_words_at(rows, cols, tt, rule.random_words)
             acc = (prng.bernoulli_words_at(rows, cols, tt, p_force)
                    if p_force > 0 else None)
             return rulespec.step_planes_rule(s, tt, rule, y0=row0,
-                                             chi=chi, accel=acc)
+                                             words=words, accel=acc)
 
         if k:
             # Moments cadence: Python-unrolled round (depth is small) --

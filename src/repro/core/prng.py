@@ -1,11 +1,12 @@
 """Counter-based pseudo-random bits for collision chirality and forcing.
 
-The FHP update needs one cheap random bit per node per step (chirality of
-two-/four-body rotations) and one uniform per node per step (forcing with
-probability p).  A stateful PRNG array would double the memory traffic of a
-memory-bound algorithm, so we hash the (position, time, salt) counter
-instead - the TPU analogue of the paper's implicit per-thread RNG, with
-bitwise ops only (VPU-native).
+The FHP update needs cheap random bits per node per step -- one for
+FHP-II (chirality of two-/four-body rotations), two for FHP-III (the
+member of a collision class) -- and one uniform per node per step
+(forcing with probability p).  A stateful PRNG array would double the
+memory traffic of a memory-bound algorithm, so we hash the (position,
+time, salt) counter instead - the TPU analogue of the paper's implicit
+per-thread RNG, with bitwise ops only (VPU-native).
 
 The mix is a 32-bit xorshift/multiply hash (splitmix-style).  Statistical
 quality is far above what FHP chirality needs (a coin flip per node).
@@ -47,9 +48,20 @@ def counter_u32(shape, t, salt: int, y0: int = 0, x0: int = 0) -> jnp.ndarray:
     return hash_u32(ctr ^ tt)
 
 
-def chirality_bits(shape, t, y0: int = 0, x0: int = 0) -> jnp.ndarray:
-    """One random bit per node, as uint8 in {0, 1}."""
-    return (counter_u32(shape, t, salt=0x11, y0=y0, x0=x0) >> 31).astype(jnp.uint8)
+# Salt of the k-th random word a collision draws per site-step: 0x11 is
+# the chirality word (FHP-II's only one, FHP-III's c0), 0x33 FHP-III's c1.
+# The forcing comparator uses 0x2200 + i (``bernoulli_words``).
+RANDOM_SALTS = (0x11, 0x33)
+
+
+def random_bits(shape, t, n: int, y0: int = 0, x0: int = 0) -> jnp.ndarray:
+    """``n`` random bits per node packed as ``r = sum(bit_k << k)``, uint8
+    in [0, 2**n): bit k from the k-th of ``RANDOM_SALTS`` (byte path)."""
+    r = jnp.zeros(shape, jnp.uint8)
+    for k, salt in enumerate(RANDOM_SALTS[:n]):
+        bit = counter_u32(shape, t, salt=salt, y0=y0, x0=x0) >> 31
+        r = r | (bit.astype(jnp.uint8) << k)
+    return r
 
 
 def bernoulli(shape, t, p: float, salt: int = 0x22, y0: int = 0, x0: int = 0):
@@ -145,3 +157,15 @@ def bernoulli_words_at(rows, cols, t, p: float, salt: int = 0x22) -> jnp.ndarray
 def chirality_words(shape_words, t, y0: int = 0, xw0: int = 0) -> jnp.ndarray:
     """One random chirality bit per node, packed 32 nodes per uint32 word."""
     return word_u32(shape_words, t, salt=0x11, y0=y0, xw0=xw0)
+
+
+def random_words(shape_words, t, n: int, y0: int = 0, xw0: int = 0):
+    """The ``n`` random words a collision draws per site-step (a tuple;
+    word k under the k-th of ``RANDOM_SALTS``)."""
+    return tuple(word_u32(shape_words, t, salt=s, y0=y0, xw0=xw0)
+                 for s in RANDOM_SALTS[:n])
+
+
+def random_words_at(rows, cols, t, n: int):
+    """``random_words`` for explicit (broadcastable) coordinate arrays."""
+    return tuple(word_u32_at(rows, cols, t, salt=s) for s in RANDOM_SALTS[:n])

@@ -1,4 +1,4 @@
-"""FHP-II rule system: directions, lattice offsets, collision rules, LUT builder.
+"""FHP rule system: directions, lattice offsets, collision tables, LUT builder.
 
 State encoding (paper Fig. 1): bits 0-5 = moving particles along the six
 triangular-lattice directions, bit 6 = rest particle, bit 7 = solid/boundary
@@ -14,12 +14,30 @@ Doubled integer coordinates keep momentum arithmetic exact:
 The triangular lattice is mapped onto a rectangular array (paper Fig. 3) with
 odd rows shifted right by half a lattice constant.  Neighbour x-offsets then
 depend on the row parity of the *source* node; see OFFSETS.
+
+Two collision tables:
+
+* FHP-II (``fhp2_rules``): head-on pairs, symmetric triples, two head-on
+  pairs and the rest-particle exchange, one chirality bit per site;
+* FHP-III (``fhp3_table``), collision-saturated (Frisch, d'Humieres,
+  Hasslacher, Lallemand, Pomeau, Rivet, Complex Systems 1, 649, 1987):
+  the 128 fluid states fall into classes of equal (mass, sum cx2, sum
+  cy); every state whose class has another member collides, into one of
+  the other members.  12 classes of 2, 14 of 3 and 2 of 5: 76 colliding
+  states.  Two random bits per site, ``c0`` and ``c1``, pick the member:
+  ``r = c0 + 2 * c1`` indexes the other members in ascending order of
+  state value, modulo their number (a class of 3 reads ``c0`` alone, a
+  class of 2 neither).  The published rule fixes the classes; this
+  indexing is the repository's convention.
+
+Solid sites bounce every mover back (i -> i+3) and keep the rest particle,
+under both tables.
 """
 from __future__ import annotations
 
 import dataclasses
 from functools import lru_cache
-from typing import FrozenSet, Optional, Tuple
+from typing import Dict, FrozenSet, Optional, Tuple
 
 import numpy as np
 
@@ -62,22 +80,15 @@ class Rule:
     ``moving_in`` and (if ``rest_in`` is not None) whose rest bit equals
     ``rest_in``.  ``out_c0``/``out_c1`` are the two chirality-resolved
     output moving sets (equal when the rule is achiral); ``rest_out`` is
-    the new rest bit, None for "unchanged", or a per-chirality
-    ``(r0, r1)`` tuple (FHP-III's rotate-vs-split outcomes differ in
-    rest-particle count).
+    the new rest bit, None for "unchanged".
     """
 
     moving_in: FrozenSet[int]
     rest_in: Optional[bool]
     out_c0: FrozenSet[int]
     out_c1: FrozenSet[int]
-    rest_out: object
+    rest_out: Optional[bool]
     name: str
-
-    def rest_outs(self) -> Tuple[Optional[bool], Optional[bool]]:
-        if isinstance(self.rest_out, tuple):
-            return self.rest_out
-        return (self.rest_out, self.rest_out)
 
 
 def fhp2_rules() -> Tuple[Rule, ...]:
@@ -108,51 +119,47 @@ def fhp2_rules() -> Tuple[Rule, ...]:
     return tuple(rules)
 
 
-def fhp3_rules() -> Tuple[Rule, ...]:
-    """FHP-III-style extension: additional mass-3 conversion channels
-    (head-on pair + rest <-> symmetric triple), raising the collision
-    saturation (lower viscosity).  One chirality bit selects among two
-    members of each outcome class -- the full FHP-III table randomises
-    over all class members, so this is the 1-bit restriction of it
-    (documented approximation; conservation is still audited per entry).
-    """
-    t0 = frozenset({0, 2, 4})
-    t1 = frozenset({1, 3, 5})
-    rules = []
-    for i in range(3):
-        pair = frozenset({i, opposite(i)})
-        # head-on without rest: rotate (as FHP-II, but rest now excluded)
-        rules.append(Rule(pair, False, rotate_set(pair, 1),
-                          rotate_set(pair, -1), None, f"head-on-{i}"))
-        # head-on + rest -> one of the symmetric triples (fusion)
-        rules.append(Rule(pair, True, t0, t1, False, f"pair-rest-fuse-{i}"))
-    # triple without rest: chirality picks rotate (rest stays 0) vs
-    # fission into a head-on pair + rest particle
-    rules.append(Rule(t0, False, t1, frozenset({0, 3}), (None, True),
-                      "triple0"))
-    rules.append(Rule(t1, False, t0, frozenset({1, 4}), (None, True),
-                      "triple1"))
-    # triple + rest: rotate with spectator (as FHP-II)
-    rules.append(Rule(t0, True, t1, t1, None, "triple0-rot"))
-    rules.append(Rule(t1, True, t0, t0, None, "triple1-rot"))
-    for i in range(3):
-        quad = frozenset({i, (i + 1) % 6, opposite(i), (opposite(i) + 1) % 6})
-        rules.append(Rule(quad, None, rotate_set(quad, 1), rotate_set(quad, -1),
-                          None, f"four-body-{i}"))
-    for i in range(N_DIR):
-        single = frozenset({i})
-        split = frozenset({(i - 1) % 6, (i + 1) % 6})
-        rules.append(Rule(single, True, split, split, False, f"rest-split-{i}"))
-        rules.append(Rule(split, False, single, single, True, f"rest-merge-{i}"))
-    return tuple(rules)
+def fhp2_table() -> Dict[int, Tuple[int, int]]:
+    """FHP-II: each colliding fluid state -> its output for chirality 0
+    and 1 (``fhp2_rules``, whose exact-match patterns must not overlap)."""
+    table = {}
+    for r in fhp2_rules():
+        for rest in ([r.rest_in] if r.rest_in is not None else [False, True]):
+            s = _set_to_bits(r.moving_in) | (REST_MASK if rest else 0)
+            if s in table:
+                raise ValueError(f"rule overlap: {r.name} at state {s}")
+            rest_new = rest if r.rest_out is None else r.rest_out
+            table[s] = tuple(_set_to_bits(out) | (REST_MASK if rest_new
+                                                  else 0)
+                             for out in (r.out_c0, r.out_c1))
+    return table
 
 
-def fhp_rules(variant: str = "fhp2") -> Tuple[Rule, ...]:
-    if variant == "fhp2":
-        return fhp2_rules()
-    if variant == "fhp3":
-        return fhp3_rules()
-    raise ValueError(variant)
+# Random bits a site-step draws, per FHP variant.
+RANDOM_WORDS = {"fhp2": 1, "fhp3": 2}
+
+
+def fluid_classes() -> Tuple[Tuple[int, ...], ...]:
+    """The 128 fluid states grouped by (mass, sum cx2, sum cy), each class
+    in ascending order of state value, the classes ordered by their first
+    member."""
+    groups = {}
+    for s in range(REST_MASK << 1):
+        groups.setdefault((mass_of(s),) + momentum_of(s), []).append(s)
+    return tuple(sorted(tuple(g) for g in groups.values()))
+
+
+@lru_cache(maxsize=None)
+def fhp3_table() -> Dict[int, Tuple[int, int, int, int]]:
+    """FHP-III: each colliding fluid state -> its output for ``r = c0 + 2
+    * c1`` = 0, 1, 2, 3 (module docstring)."""
+    table = {}
+    for cls in fluid_classes():
+        for s in cls:
+            others = [o for o in cls if o != s]
+            if others:
+                table[s] = tuple(others[r % len(others)] for r in range(4))
+    return table
 
 
 def _set_to_bits(s: FrozenSet[int]) -> int:
@@ -185,42 +192,24 @@ def bounce_back(state: int) -> int:
 
 @lru_cache(maxsize=None)
 def build_lut(variant: str = "fhp2") -> np.ndarray:
-    """Build the 2x256 collision LUT (axis 0 = chirality bit).
+    """Build the ``(2 ** RANDOM_WORDS[variant], 256)`` collision LUT: axis
+    0 is the site's random bits ``r`` (FHP-II: the chirality bit; FHP-III:
+    ``c0 + 2 * c1``).
 
     Verifies mass and momentum conservation for every fluid entry and
     mass conservation + momentum reversal for solid entries.
     """
-    rules = fhp_rules(variant)
-    # Exact-match patterns must be mutually exclusive.
-    seen = {}
-    for r in rules:
-        for rest in ([r.rest_in] if r.rest_in is not None else [False, True]):
-            key = (_set_to_bits(r.moving_in), rest)
-            if key in seen:
-                raise ValueError(f"rule overlap: {r.name} vs {seen[key]}")
-            seen[key] = r.name
-
-    lut = np.zeros((2, 256), dtype=np.uint8)
+    n_rows = 1 << RANDOM_WORDS[variant]
+    table = fhp2_table() if variant == "fhp2" else fhp3_table()
+    lut = np.zeros((n_rows, 256), dtype=np.uint8)
     for s in range(256):
         if s & SOLID_MASK:
-            out0 = out1 = bounce_back(s)
+            lut[:, s] = bounce_back(s)
         else:
-            moving = frozenset(i for i in range(N_DIR) if s & (1 << i))
-            rest = bool(s & REST_MASK)
-            out0 = out1 = s
-            for r in rules:
-                if r.moving_in == moving and (r.rest_in is None or r.rest_in == rest):
-                    r0, r1 = r.rest_outs()
-                    rest0 = rest if r0 is None else r0
-                    rest1 = rest if r1 is None else r1
-                    out0 = _set_to_bits(r.out_c0) | (REST_MASK if rest0 else 0)
-                    out1 = _set_to_bits(r.out_c1) | (REST_MASK if rest1 else 0)
-                    break
-        lut[0, s] = out0
-        lut[1, s] = out1
+            lut[:, s] = table.get(s, s)
 
     # --- conservation audit (runs once, cached) ---
-    for chi in range(2):
+    for chi in range(n_rows):
         for s in range(256):
             o = int(lut[chi, s])
             if s & SOLID_MASK:
@@ -236,5 +225,5 @@ def build_lut(variant: str = "fhp2") -> np.ndarray:
 
 
 def lut_flat(variant: str = "fhp2") -> np.ndarray:
-    """LUT flattened to (512,) with index = chirality<<8 | state."""
-    return build_lut(variant).reshape(512).copy()
+    """LUT flattened with index = r << 8 | state (``build_lut``)."""
+    return build_lut(variant).reshape(-1).copy()

@@ -17,14 +17,19 @@ that per-rule residue:
                       ``core.rules`` -- the same table that builds the
                       LUT; for BML, the two alternating deterministic
                       sub-steps selected by the step parity);
-* ``needs_rng``    -- whether the circuit consumes chirality bits (the
-                      kernel skips the in-kernel hash entirely when not);
+* ``random_words`` -- how many random words the circuit draws per
+                      site-step: 0 (BML; the kernel skips the in-kernel
+                      hash entirely), 1 (FHP-II's chirality), 2
+                      (FHP-III's ``c0``, ``c1``; salts in
+                      ``prng.RANDOM_SALTS``);
 * ``n_substeps``   -- the sub-step schedule length (BML alternates 2);
 * ``solid_plane``  -- index of the static geometry plane, or None for
                       rules without obstacles (gates static-solid mode);
 * ``force``        -- the optional body-force pass (FHP only).
 
-Registered rules: ``fhp2``, ``fhp3`` (8 planes, RNG, solid plane 7) and
+Registered rules: ``fhp2`` (FHP-II: 8 planes, one random word, solid
+plane 7), ``fhp3`` (FHP-III, collision-saturated: the same layout, two
+random words; ``core.rules``) and
 ``bml`` (Biham--Middleton--Levine traffic: 2 planes, zero RNG, two
 alternating deterministic sub-steps -- east cars move on even t, north
 cars on odd t, a car advances iff its destination was empty before the
@@ -43,8 +48,11 @@ uses them as its jnp fallback for every rule.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 
@@ -77,12 +85,13 @@ class Tap:
 class RuleSpec:
     """A complete bit-sliced CA rule (see module docstring).
 
-    ``collide(streamed, chi, t)`` maps the streamed tap list (one array
-    per tap, in ``taps`` order) to the ``n_planes`` output planes; it
-    must be pointwise boolean (representation-agnostic: packed uint32
-    words or {0,1} arrays).  ``chi`` is None when ``needs_rng`` is
-    False; ``t`` is the (possibly traced) global step counter -- the
-    sub-step schedule selects on ``t % n_substeps``.
+    ``collide(streamed, words, t)`` maps the streamed tap list (one
+    array per tap, in ``taps`` order) to the ``n_planes`` output planes;
+    it must be pointwise boolean (representation-agnostic: packed uint32
+    words or {0,1} arrays).  ``words`` is the tuple of the site-step's
+    ``random_words`` random words (empty for a rule that draws none);
+    ``t`` is the (possibly traced) global step counter -- the sub-step
+    schedule selects on ``t % n_substeps``.
 
     ``mass_planes`` are the planes whose popcount sum is the conserved
     particle count; ``per_plane_conserved`` claims each mass plane's
@@ -96,9 +105,9 @@ class RuleSpec:
     name: str
     n_planes: int
     taps: Tuple[Tap, ...]
-    collide: Callable[[Sequence[jnp.ndarray], Optional[jnp.ndarray], object],
-                      List[jnp.ndarray]]
-    needs_rng: bool
+    collide: Callable[[Sequence[jnp.ndarray], Tuple[jnp.ndarray, ...],
+                       object], List[jnp.ndarray]]
+    random_words: int
     oracle_step: Callable[..., jnp.ndarray]
     init_bytes: Callable[[int, int, float, int], np.ndarray]
     n_substeps: int = 1
@@ -112,6 +121,8 @@ class RuleSpec:
 
     def __post_init__(self):
         assert self.n_planes >= 1
+        assert 0 <= self.random_words <= len(prng.RANDOM_SALTS), \
+            self.random_words
         for tap in self.taps:
             assert 0 <= tap.plane < self.n_planes, tap
         if self.solid_plane is not None:
@@ -158,7 +169,10 @@ def _fhp_taps() -> Tuple[Tap, ...]:
 
 
 def _fhp_spec(variant: str) -> RuleSpec:
-    def collide(streamed, chi, t):
+    n_words = rules.RANDOM_WORDS[variant]
+
+    def collide(streamed, words, t):
+        chi = words[0] if n_words == 1 else tuple(words)
         return boolean.collide_planes(streamed, chi, variant)
 
     def oracle_step(state, t, chi=None):
@@ -176,7 +190,8 @@ def _fhp_spec(variant: str) -> RuleSpec:
 
     return RuleSpec(
         name=variant, n_planes=8, taps=_fhp_taps(), collide=collide,
-        needs_rng=True, oracle_step=oracle_step, init_bytes=init_bytes,
+        random_words=n_words, oracle_step=oracle_step,
+        init_bytes=init_bytes,
         n_substeps=1, solid_plane=rules.SOLID_BIT,
         force=boolean.force_planes,
         conserves_mass=True, conserves_momentum=True,
@@ -204,7 +219,7 @@ _BML_TAPS = (
 )
 
 
-def _bml_collide(streamed, chi, t):
+def _bml_collide(streamed, words, t):
     """One BML sub-step: even t moves east cars, odd t moves north cars.
 
     A car advances iff its destination cell was empty *before* the
@@ -257,7 +272,7 @@ register_rule(_fhp_spec("fhp2"))
 register_rule(_fhp_spec("fhp3"))
 register_rule(RuleSpec(
     name="bml", n_planes=2, taps=_BML_TAPS, collide=_bml_collide,
-    needs_rng=False, oracle_step=bml_step_bytes, init_bytes=bml_init_bytes,
+    random_words=0, oracle_step=bml_step_bytes, init_bytes=bml_init_bytes,
     n_substeps=2, solid_plane=None, force=None,
     conserves_mass=True, conserves_momentum=False,
     mass_planes=(0, 1), per_plane_conserved=True,
@@ -296,18 +311,20 @@ def stream_taps(planes: jnp.ndarray, taps: Sequence[Tap],
 
 def step_planes_rule(planes: jnp.ndarray, t, spec: RuleSpec,
                      p_force: float = 0.0, y0: int = 0, xw0: int = 0, *,
-                     chi=None, accel=None) -> jnp.ndarray:
+                     words=None, accel=None) -> jnp.ndarray:
     """One fused update of ``spec`` on packed ``(..., n_planes, H, Wd)``
     planes -- stream the taps, run the collision circuit, apply the
-    optional force pass.  For the FHP specs this is bit-identical to
-    ``bitplane.step_planes`` (conformance-tested)."""
+    optional force pass.  ``words`` overrides the counter-based random
+    words (a tuple of ``spec.random_words``).  For the FHP specs this is
+    bit-identical to ``bitplane.step_planes`` (conformance-tested)."""
     assert planes.shape[-3] == spec.n_planes, \
         (planes.shape, spec.name, spec.n_planes)
     shape_words = planes.shape[-2:]
     streamed = stream_taps(planes, spec.taps, row0=y0)
-    if spec.needs_rng and chi is None:
-        chi = prng.chirality_words(shape_words, t, y0=y0, xw0=xw0)
-    out = spec.collide(streamed, chi if spec.needs_rng else None, t)
+    if words is None:
+        words = prng.random_words(shape_words, t, spec.random_words,
+                                  y0=y0, xw0=xw0)
+    out = spec.collide(streamed, tuple(words), t)
     if p_force or accel is not None:
         assert spec.force is not None, \
             f"rule {spec.name!r} has no force pass"
@@ -320,7 +337,6 @@ def step_planes_rule(planes: jnp.ndarray, t, spec: RuleSpec,
 
 def run_planes_rule(planes: jnp.ndarray, steps: int, spec: RuleSpec,
                     p_force: float = 0.0, t0: int = 0) -> jnp.ndarray:
-    import jax
     def body(i, s):
         return step_planes_rule(s, t0 + i, spec, p_force)
     return jax.lax.fori_loop(0, int(steps), body, planes)
@@ -334,7 +350,6 @@ def run_planes_rule(planes: jnp.ndarray, steps: int, spec: RuleSpec,
 # ---------------------------------------------------------------------------
 
 def _pop(p: jnp.ndarray) -> jnp.ndarray:
-    import jax
     dt = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
     return jax.lax.population_count(p).sum(axis=(-2, -1), dtype=dt)
 
@@ -503,7 +518,6 @@ def compute_moments(planes: jnp.ndarray, ms: MomentSpec) -> jnp.ndarray:
     lanes).  Bit-identical to the kernel's fused accumulation -- fixed
     int32 regardless of the x64 flag, matching the kernel's native
     accumulator width (``require_moment_headroom`` guards overflow)."""
-    import jax
     vals = []
     for t in ms.terms:
         p = planes[..., t[0], :, :]
@@ -544,16 +558,69 @@ def require_moment_headroom(ms: MomentSpec, n_sites: int) -> None:
 
 def oracle_run(state, steps: int, spec: RuleSpec, t0: int = 0):
     """Advance the byte oracle ``steps`` steps, drawing the *word-RNG*
-    chirality stream (expanded to bytes) for rules that need it -- so the
-    oracle is bit-comparable with the packed/Pallas paths at any T."""
+    random words (expanded to bytes: ``r = sum(bit of word k << k)``) for
+    rules that draw any -- so the oracle is bit-comparable with the
+    packed/Pallas paths at any T."""
     s = jnp.asarray(state)
     h, w = s.shape[-2:]
+    shifts = jnp.arange(32, dtype=jnp.uint32)
     for k in range(int(steps)):
         chi = None
-        if spec.needs_rng:
-            chi_w = prng.chirality_words((h, w // 32), t0 + k)
-            shifts = jnp.arange(32, dtype=jnp.uint32)
-            chi = ((chi_w[..., None] >> shifts) & 1).astype(_U8)
-            chi = chi.reshape(h, w)
+        if spec.random_words:
+            chi = jnp.zeros((h, w), _U8)
+            words = prng.random_words((h, w // 32), t0 + k,
+                                      spec.random_words)
+            for i, word in enumerate(words):
+                bits = ((word[..., None] >> shifts) & 1).astype(_U8)
+                chi = chi | (bits.reshape(h, w) << i)
         s = spec.oracle_step(s, t0 + k, chi=chi)
     return s
+
+
+@functools.lru_cache(maxsize=None)
+def _ops_per_word_step(name: str, pq: int) -> int:
+    spec = get_rule(name)
+    p_force = pq / (1 << prng.BERNOULLI_BITS)
+    block = (8, 128)                       # one (sublane, lane) vreg tile
+
+    def body(planes, rows, cols, t):
+        streamed = stream_taps(planes, spec.taps)
+        words = prng.random_words_at(rows, cols, t, spec.random_words)
+        out = spec.collide(streamed, words, t)
+        if pq:
+            out = spec.force(out, prng.bernoulli_words_at(rows, cols, t,
+                                                          p_force))
+        return out
+
+    u32 = jnp.uint32
+    jaxpr = jax.make_jaxpr(body)(
+        jax.ShapeDtypeStruct((spec.n_planes,) + block, u32),
+        jax.ShapeDtypeStruct((block[0], 1), u32),
+        jax.ShapeDtypeStruct((1, block[1]), u32),
+        jax.ShapeDtypeStruct((), u32))
+    return _count_eqns(jaxpr.jaxpr)
+
+
+def _count_eqns(jaxpr) -> int:
+    """Equations of ``jaxpr``, those of nested jaxprs (pjit, ...) counted
+    in place of the equation that holds them."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        subs = [v for v in eqn.params.values()
+                if isinstance(v, (jex.core.Jaxpr, jex.core.ClosedJaxpr))]
+        if subs:
+            n += sum(_count_eqns(getattr(j, "jaxpr", j)) for j in subs)
+        else:
+            n += 1
+    return n
+
+
+def ops_per_word_step(spec: RuleSpec, p_force: float = 0.0) -> int:
+    """Operations per packed word per CA step of ``spec``: the equations
+    in the jaxpr of one step's body -- streaming the taps, drawing the
+    random words, the collision circuit and, when ``p_force`` > 0, the
+    forcing coin and pass -- traced on one uint32 word tile.  Every
+    equation is one VPU operation per word, so this is the numerator of
+    the kernel's VPU roofline; the benchmark reads it from the kernel's
+    ``kernel.build`` telemetry event."""
+    return _ops_per_word_step(spec.name, prng.quantize_p(p_force))

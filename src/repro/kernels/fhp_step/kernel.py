@@ -123,12 +123,15 @@ everything FHP-specific above is really the spec's contract:
   1``, which is exactly the one-row/one-word-per-side-per-step budget
   the T-row/T-word halo aprons were sized for, so temporal and 2-D
   blocking work unchanged for every rule;
-* ``spec.collide(streamed, chi, t)`` is the pointwise boolean collision
-  pass over the streamed taps; ``t`` is traced, so multi-sub-step rules
-  (BML's alternating east/north moves) select on ``t % n_substeps``
-  inside one fused launch;
-* ``spec.needs_rng`` gates the in-kernel hash: RNG-free rules skip the
-  chirality computation entirely (and accept any ``rng_in_kernel``);
+* ``spec.collide(streamed, words, t)`` is the pointwise boolean
+  collision pass over the streamed taps; ``t`` is traced, so
+  multi-sub-step rules (BML's alternating east/north moves) select on
+  ``t % n_substeps`` inside one fused launch;
+* ``spec.random_words`` is how many hashes the kernel draws per word
+  and step (one per salt of ``prng.RANDOM_SALTS``; FHP-II 1, FHP-III 2):
+  RNG-free rules skip the hash entirely (and accept any
+  ``rng_in_kernel``), and a rule that draws one compiles to the one
+  chirality hash;
 * ``spec.solid_plane`` (must be the last plane) gates static-solid
   mode; ``spec.force`` gates the forcing pass.
 
@@ -146,7 +149,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro import telemetry
 from repro.core import rulespec
+from repro.core.prng import RANDOM_SALTS
 
 WORD = 32
 _U32 = jnp.uint32
@@ -262,7 +267,7 @@ def _bernoulli_words(rows, cols, t, pq: int, salt: int) -> jnp.ndarray:
 
 def _fused_step(cur: jnp.ndarray, rows_abs: jnp.ndarray, cols_abs, t,
                 pq: int, rng_in_kernel: bool, spec,
-                chi_pre=None, acc_pre=None, solid=None,
+                words_pre=(), acc_pre=None, solid=None,
                 shrink_x: bool = False) -> jnp.ndarray:
     """One stream->collide(->force) update of an extended row stack.
 
@@ -304,14 +309,14 @@ def _fused_step(cur: jnp.ndarray, rows_abs: jnp.ndarray, cols_abs, t,
 
     # --- collide (the rule's boolean circuit; FHP: LUT-equivalent algebra) --
     tt = jnp.asarray(t, _U32)
-    chi = None
-    if rng_in_kernel and (spec.needs_rng or pq > 0):
+    words = words_pre
+    if rng_in_kernel and (spec.random_words or pq > 0):
         rows_blk = rows_abs[1:n - 1].astype(_U32)
         cols_blk = cols_abs[:, xs].astype(_U32)
-    if spec.needs_rng:
-        chi = (_word_u32(rows_blk, cols_blk, tt, salt=0x11)
-               if rng_in_kernel else chi_pre)
-    planes = spec.collide(streamed, chi, t)
+    if rng_in_kernel:
+        words = tuple(_word_u32(rows_blk, cols_blk, tt, salt=salt)
+                      for salt in RANDOM_SALTS[:spec.random_words])
+    planes = spec.collide(streamed, words, t)
 
     # --- force (momentum injection with probability p) ----------------------
     if pq > 0:
@@ -332,7 +337,8 @@ def fhp_kernel(s_ref, *rest,
                rng_in_kernel: bool, variant: str = "fhp2",
                extended: bool = False, static_solid: bool = False,
                record_steps: tuple = (), moment_terms: tuple = (),
-               moment_coeffs: tuple = (), moment_bounds=None):
+               moment_coeffs: tuple = (), moment_bounds=None,
+               interpret: bool = False):
     """``steps`` fused FHP updates for a ``(bh, bw)`` tile.
 
     Refs (inputs first, output last, per pallas_call convention): the
@@ -344,7 +350,8 @@ def fhp_kernel(s_ref, *rest,
     ``(bh, bw)`` tiles (the 3x3 y-x neighbourhood, row-major) when x is
     blocked -- then, with ``static_solid``, the same number of views of
     the read-only solid plane, then -- when ``rng_in_kernel`` is False
-    (T=1 only) -- the precomputed chirality / force planes for the tile,
+    (T=1 only) -- the precomputed random words (one plane per
+    ``spec.random_words``) and force plane for the tile,
     and finally the output tile.  Grid is ``(B, H/bh, Wd/bw)``: axis 0 is
     the ensemble lane, axis 1 the row band, axis 2 the word block.
 
@@ -373,6 +380,12 @@ def fhp_kernel(s_ref, *rest,
     reduction to array-local rows ``[r0, r1)`` x words ``[c0, c1)`` --
     extended mode's validity window, which also drops the row/word
     padding ``ops.run_extended`` appends.
+
+    ``interpret`` (the CPU's interpret mode) ends each unrolled step in
+    an optimization barrier: XLA's CPU compiler would otherwise fuse
+    each step's circuit into every consumer in the next step, and the
+    compile grows steeply with T (FHP-III at T=4: ~295 s against ~6 s).
+    The chip's program has no barrier.
     """
     spec = rulespec.get_rule(variant)
     x_blocked = bw < wd
@@ -465,15 +478,19 @@ def fhp_kernel(s_ref, *rest,
                   solid_band[s + 1:s + n - 1]
         else:
             sol = None
-        if rng_in_kernel or not spec.needs_rng:
+        if rng_in_kernel or not spec.random_words:
             cur = _fused_step(cur, rows_abs, cols_abs, t0 + s, pq,
                               rng_in_kernel, spec, solid=sol,
                               shrink_x=x_blocked)
         else:
+            nw = spec.random_words
             cur = _fused_step(cur, rows_abs, cols_abs, t0 + s, pq, False,
-                              spec, chi_pre=extra_refs[0][...],
-                              acc_pre=extra_refs[-1][...] if pq > 0 else None,
+                              spec,
+                              words_pre=tuple(r[...] for r in extra_refs[:nw]),
+                              acc_pre=extra_refs[nw][...] if pq > 0 else None,
                               solid=sol, shrink_x=x_blocked)
+        if interpret:
+            cur = jax.lax.optimization_barrier(cur)
         if record_steps and s in record_steps:
             oy = (cur.shape[1] - bh) // 2
             ox = (cur.shape[2] - bw) // 2
@@ -513,6 +530,13 @@ def make_fhp_step(h: int, wd: int, *, bh: int, pq: int,
     where ``partials`` is ``(batch, H/bh, Wd/bw, len(record_steps),
     n_moments)`` int32 per-block moment partials (``fhp_kernel``
     docstring); callers sum over the block axes.
+
+    With the default ``telemetry`` on, each build records the event
+    ``kernel.build`` (``rule``, ``random_words``, ``ops_per_word_step``
+    from ``rulespec.ops_per_word_step`` at this ``pq``, and the tile
+    ``block_rows``, ``block_words``, ``steps_per_launch``).  The build
+    runs where the caller is traced, on the host; with telemetry off
+    nothing is counted or recorded.
     """
     spec = rulespec.get_rule(variant)
     bw = bw or wd
@@ -572,15 +596,22 @@ def make_fhp_step(h: int, wd: int, *, bh: int, pq: int,
         sband = lambda fy, fx: pl.BlockSpec(
             (bh, bw), lambda b, i, j, fy=fy, fx=fx: (fy(i), fx(j)))
         in_specs += [sband(yidx(dy), xidx(dx)) for dy, dx in hood]
-    if not rng_in_kernel and spec.needs_rng:
-        in_specs.append(
-            pl.BlockSpec((bh, bw), lambda b, i, j: (i, j)))            # chi
+    if not rng_in_kernel and spec.random_words:
+        in_specs += [pl.BlockSpec((bh, bw), lambda b, i, j: (i, j))   # words
+                     for _ in range(spec.random_words)]
         if pq > 0:
             in_specs.append(
                 pl.BlockSpec((bh, bw), lambda b, i, j: (i, j)))        # accel
 
     record_steps = tuple(sorted(record_steps))
     assert all(0 <= s < steps for s in record_steps), (record_steps, steps)
+    tel = telemetry.default()
+    if tel.enabled:
+        tel.event("kernel.build", rule=variant,
+                  random_words=spec.random_words,
+                  ops_per_word_step=rulespec.ops_per_word_step(
+                      spec, pq / (1 << BERNOULLI_BITS)),
+                  block_rows=bh, block_words=bw, steps_per_launch=steps)
     kern = functools.partial(fhp_kernel, h=h, bh=bh, wd=wd, bw=bw, pq=pq,
                              steps=steps, rng_in_kernel=rng_in_kernel,
                              variant=variant, extended=extended,
@@ -588,7 +619,8 @@ def make_fhp_step(h: int, wd: int, *, bh: int, pq: int,
                              record_steps=record_steps,
                              moment_terms=moment_terms,
                              moment_coeffs=moment_coeffs,
-                             moment_bounds=moment_bounds)
+                             moment_bounds=moment_bounds,
+                             interpret=interpret)
     out_specs = pl.BlockSpec((1, np_, bh, bw), lambda b, i, j: (b, 0, i, j))
     out_shape = jax.ShapeDtypeStruct((batch, np_, h, wd), jnp.uint32)
     if record_steps:

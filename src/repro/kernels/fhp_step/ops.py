@@ -501,8 +501,9 @@ def fhp_step_pallas(planes: jnp.ndarray, t, *, p_force: float = 0.0,
     args = [scalars] + [planes] * nv
     if static_solid:
         args += [solid] * nv
-    if not rng_in_kernel and spec.needs_rng:
-        args.append(prng.chirality_words((h, wd), t, y0=y0, xw0=xw0))
+    if not rng_in_kernel and spec.random_words:
+        args += prng.random_words((h, wd), t, spec.random_words,
+                                  y0=y0, xw0=xw0)
         if pq > 0:
             args.append(prng.bernoulli_words((h, wd), t, p_force,
                                              y0=y0, xw0=xw0))

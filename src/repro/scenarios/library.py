@@ -5,6 +5,11 @@ production runs share one definition.
 Obstacle dimensions derive from the lattice shape (radius ~ H/9 etc.),
 matching the hand-rolled demos these scenarios replace at their default
 sizes.  All geometry rasterizes in global coordinates (shard-exact).
+
+The FHP flows take ``variant``: ``fhp2`` (FHP-II, the default) or
+``fhp3`` (FHP-III, collision-saturated: every state whose mass and
+momentum class has another member collides, two random bits per site;
+``core.rules``).
 """
 from __future__ import annotations
 
@@ -19,7 +24,7 @@ def cylinder(height: int = 96, width: int = 384, radius: int | None = None,
              density: float = 0.22, p_force: float = 0.03,
              seed: int = 0, variant: str = "fhp2") -> Scenario:
     """Flow past a cylinder: wake deficit + bypass acceleration.
-    ``variant`` selects the collision circuit (fhp2 / fhp3)."""
+    ``variant`` selects the collision rule (fhp2 / fhp3)."""
     r = radius if radius is not None else max(2, height // 9)
     disk = Disk(height // 2, width // 4, r)
     return Scenario(

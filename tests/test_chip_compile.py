@@ -91,10 +91,12 @@ def _legal(bh, bw, h, wd):
 # Kernel forms at real widths
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("variant,p_force", [("fhp2", 0.05), ("bml", 0.0)])
+@pytest.mark.parametrize("variant,p_force", [("fhp2", 0.05), ("bml", 0.0),
+                                             ("fhp3", 0.05)])
 def test_periodic_kernel_autotuned_tile(one_chip, variant, p_force):
     """One launch of the periodic kernel on a 16384 x 16384-site lattice
-    with the autotuner's tile: FHP-II with forcing, and BML."""
+    with the autotuner's tile: FHP-II with forcing, BML, and FHP-III
+    with forcing (the saturated circuit, two random words a site)."""
     h, wd = 16384, 512
     n = rulespec.get_rule(variant).n_planes
     bh, bw, T = ops.autotune_launch(h, wd, n_planes=n)
@@ -135,13 +137,15 @@ def test_fused_moments_batched(one_chip):
     (4096, 128, "fhp2"),      # the serve engine's lane
     (12, 4, "fhp2"),          # no power-of-two band divides H: full extent
     (16384, 512, "bml"),
+    (4096, 128, "fhp3"),
+    (65536, 2048, "fhp3"),    # the FHP-III lattice cell: 65536^2 sites
 ])
 def test_autotuned_periodic_tile_compiles(one_chip, h, wd, variant):
     n = rulespec.get_rule(variant).n_planes
     bh, bw, T = ops.autotune_launch(h, wd, n_planes=n)
     assert _legal(bh, bw, h, wd), (bh, bw)
     assert _legal(ops.pick_block_rows(h, wd, n_planes=n), wd, h, wd)
-    p_force = 0.05 if variant == "fhp2" else 0.0
+    p_force = 0.0 if variant == "bml" else 0.05
     _compile(lambda p, t: ops.run_pallas(
         p, 2 * T, p_force=p_force, t0=t, variant=variant, block_rows=bh,
         block_words=bw, steps_per_launch=T, interpret=False),

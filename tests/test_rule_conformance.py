@@ -176,13 +176,13 @@ def test_momentum_conservation_solid_free(seed):
 
 
 def test_rng_free_rules_are_deterministic():
-    """Rules with ``needs_rng=False`` must not consume randomness on any
+    """Rules with ``random_words=0`` must not consume randomness on any
     path: repeated runs agree, and toggling the kernel's RNG plumbing
     (``rng_in_kernel``) changes nothing."""
     from repro.kernels.fhp_step.ops import fhp_step_pallas
     for name in rulespec.rule_names():
         spec = rulespec.get_rule(name)
-        if spec.needs_rng:
+        if spec.random_words:
             continue
         _, planes = init(spec, seed=7)
         a = rulespec.step_planes_rule(planes, 0, spec)

@@ -103,10 +103,11 @@ def test_bounce_back_involution(s):
 
 
 @settings(max_examples=100, deadline=None)
-@given(st.integers(0, 255), st.integers(0, 1))
+@given(st.integers(0, 255), st.integers(0, 3))
 def test_fhp3_conservation_property(s, chi):
     """FHP-III's richer table honours the same conservation laws as
-    FHP-II (the exhaustive tests above pin the fhp2 default)."""
+    FHP-II, for every ``r = c0 + 2 * c1`` (the exhaustive tests above
+    pin the fhp2 default)."""
     lut = rules.build_lut("fhp3")
     o = int(lut[chi, s])
     assert rules.mass_of(o & 0x7F) == rules.mass_of(s & 0x7F)
@@ -118,24 +119,28 @@ def test_fhp3_conservation_property(s, chi):
 
 
 def test_boolean_circuit_matches_lut_all_states():
-    """The generated boolean circuit == the LUT on all 512 (state, chi)
-    combos, for every FHP variant -- the contract that lets the Pallas
-    kernel run pure vector algebra in place of the byte gather."""
+    """The generated boolean circuit == the LUT on all (state, random
+    bits) combos -- 512 for FHP-II, 1024 for FHP-III -- the contract that
+    lets the Pallas kernel run pure vector algebra in place of the byte
+    gather."""
     import jax.numpy as jnp
 
     from repro.core import bitplane, boolean
-    # 512 cells: row-major (chi, s) on a (16, 32) lattice, one word/row
-    s_all = np.arange(512, dtype=np.uint16).reshape(16, 32)
+    # 1024 cells: row-major (r, s) on a (32, 32) lattice, one word/row
+    s_all = np.arange(1024, dtype=np.uint16).reshape(32, 32)
     state = (s_all & 0xFF).astype(np.uint8)
-    chi_bits = (s_all >> 8).astype(np.uint8)
     planes = bitplane.pack(jnp.asarray(state))
-    chi = bitplane.pack_bits_from_bytes(jnp.asarray(chi_bits))
+    bit = [bitplane.pack_bits_from_bytes(
+        jnp.asarray(((s_all >> (8 + k)) & 1).astype(np.uint8)))
+        for k in range(2)]
     for variant in ("fhp2", "fhp3"):
         lut = rules.build_lut(variant)
+        r = (s_all >> 8) % lut.shape[0]
+        chi = bit[0] if variant == "fhp2" else tuple(bit)
         out = boolean.collide_planes(
             [planes[k] for k in range(8)], chi, variant)
         got = np.asarray(bitplane.unpack(jnp.stack(out)))
-        want = lut[chi_bits.astype(np.int64), state.astype(np.int64)]
+        want = lut[r.astype(np.int64), state.astype(np.int64)]
         assert (got == want).all(), variant
 
 
